@@ -1240,6 +1240,7 @@ if __name__ == "__main__":
                          "to PATH (Chrome-trace JSON, opens in "
                          "ui.perfetto.dev; audited against ServeMetrics)")
     args = ap.parse_args()
+    platform.configure_compile_cache()
     if args.family == "ssm":
         result = bench_ssm(args.requests, args.slots, args.seed, args.rate,
                            sampling=args.sampling, trace_path=args.trace)

@@ -56,7 +56,7 @@ def main() -> None:
               f"{choice.modeled_time_s * 1e6:7.2f} us  "
               f"(candidates: {({k: round(v * 1e6, 2) for k, v in choice.candidates.items()})})")
 
-    engine = Engine(gopt, plan, default_registry(interpret=True))
+    engine = Engine(gopt, plan, default_registry())
     x = jnp.asarray(np.random.default_rng(1).standard_normal((4, 3, 32, 32)).astype(np.float32))
     err = engine.verify_against_reference(x)
     print(f"engine     : optimized plan == reference graph (max err {err:.2e})")

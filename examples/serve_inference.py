@@ -54,6 +54,7 @@ def main() -> None:
                          "--devices); token streams are byte-identical "
                          "across widths")
     args = ap.parse_args()
+    platform.configure_compile_cache()
 
     cfg = get_config("qwen3-1.7b").reduced(n_layers=2, d_model=128, d_ff=256,
                                            vocab=211)

@@ -311,7 +311,8 @@ def sharded_op_desc(op: OpDesc, role: str, tp: int) -> Optional[OpDesc]:
         if d["h"] % tp:
             return None
         return OpDesc.attention(d["b"], d["q"], d["kv"], d["h"] // tp,
-                                d["d"], dtype=op.dtype, label=op.label)
+                                d["d"], dtype=op.dtype, label=op.label,
+                                kv_heads=d["h"] // tp // d.get("g", 1))
     return None
 
 

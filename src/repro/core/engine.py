@@ -7,8 +7,8 @@ jit-compiled JAX callable.  Backends:
 
   'xla'            -> repro.core.ref_ops (the vendor-library lane)
   'pallas_*'       -> repro.kernels.ops tuned kernels (WPK-generated lane);
-                      on this CPU-only container they execute in Pallas
-                      interpret mode, on TPU they compile natively.
+                      they compile natively on a TPU and run in the
+                      Pallas interpreter elsewhere (`kernels.tpu`).
 
 Because the whole executed graph is one jit region, XLA still fuses across
 our op boundaries — the engine's value is *which implementation* each compute
@@ -38,24 +38,23 @@ def _xla_backend(node_op: str):
     return run
 
 
-def default_registry(interpret: bool = True) -> BackendRegistry:
+def default_registry() -> BackendRegistry:
     """Registry with the XLA lane plus every tuned Pallas template."""
     from repro.kernels import ops as kops  # local import: kernels are optional
 
     return {
         "xla": None,  # resolved per-op via ref_ops
         "pallas_matmul": lambda inputs, attrs, config: kops.matmul(
-            *inputs, config=config, activation=attrs.get("activation"),
-            interpret=interpret),
+            *inputs, config=config, activation=attrs.get("activation")),
         "pallas_conv2d": lambda inputs, attrs, config: kops.conv2d(
             *inputs, stride=attrs.get("stride", 1),
             padding=attrs.get("padding", "SAME"),
             layout=attrs.get("layout", "NCHW"),
             activation=attrs.get("activation"),
-            config=config, interpret=interpret),
+            config=config),
         "pallas_attention": lambda inputs, attrs, config: kops.attention(
             *inputs, causal=attrs.get("causal", True),
-            config=config, interpret=interpret),
+            config=config),
     }
 
 

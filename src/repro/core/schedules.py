@@ -9,10 +9,13 @@ the TPU equivalents of the paper's CUDA thread-block dims (T_x,T_y,T_z) and
 per-thread tiles (Tile_x,Tile_y,Tile_z,Tile_rz).
 
 The CUDA validity constraint ("total threads per block <= 1024") becomes the
-VMEM-residency constraint: all live blocks, double-buffered, must fit in
-VMEM.  `Template.validate` enforces it; the searches only ever propose valid
-configurations (§2.3 Step1 "any randomly generated configuration will be
-verified first").
+VMEM-residency constraint: every block the Pallas pipeline holds (inputs
+and output, double-buffered) plus the kernel's scratch and f32 temporaries
+must fit in the chip's `vmem_budget_bytes` — the same limit every
+`pallas_call` hands the compiler (`kernels.tpu`), so what the search admits
+is exactly what the compiler is told.  `Template.validate` enforces it; the
+searches only ever propose valid configurations (§2.3 Step1 "any randomly
+generated configuration will be verified first").
 """
 
 from __future__ import annotations
@@ -73,10 +76,14 @@ class OpDesc:
             dtype, activation, label)
 
     @staticmethod
-    def attention(b, q, kv, heads, head_dim, dtype="bfloat16", label="") -> "OpDesc":
-        return OpDesc.make(
-            "attention", {"b": b, "q": q, "kv": kv, "h": heads, "d": head_dim},
-            dtype, None, label)
+    def attention(b, q, kv, heads, head_dim, dtype="bfloat16", label="",
+                  kv_heads=None) -> "OpDesc":
+        """`kv_heads` < `heads` (GQA) records the group size as dim `g`: the
+        kernels stack a group's query heads into one tile of rows."""
+        dims = {"b": b, "q": q, "kv": kv, "h": heads, "d": head_dim}
+        if kv_heads and heads // kv_heads > 1:
+            dims["g"] = heads // kv_heads
+        return OpDesc.make("attention", dims, dtype, None, label)
 
     def gemm_view(self) -> Tuple[int, int, int]:
         """(M, N, K) of the underlying GEMM (implicit GEMM for conv)."""
@@ -170,9 +177,11 @@ class Template:
 
 
 def _vmem_matmul_bytes(bm: int, bn: int, bk: int, dtype) -> int:
+    """VMEM the matmul kernel (`kernels/matmul.py`) holds for one config."""
     item = np.dtype(dtype).itemsize
-    # A-block + B-block double-buffered, f32 accumulator single-buffered.
-    return 2 * (bm * bk + bk * bn) * item + bm * bn * 4
+    return (2 * (bm * bk + bk * bn + bm * bn) * item  # A, B, out: 2 buffers
+            + 2 * 32 * bn                # bias block: 2 buffers, one tile high
+            + 2 * bm * bn * 4)           # f32 accumulator + one K step's dot
 
 
 class MatmulTemplate(Template):
@@ -208,7 +217,7 @@ class MatmulTemplate(Template):
         if cfg["bn"] % chip.lane or cfg["bk"] % chip.lane:
             return False
         need = _vmem_matmul_bytes(cfg["bm"], cfg["bn"], cfg["bk"], op.dtype)
-        return need <= 0.9 * chip.vmem_bytes
+        return need <= chip.vmem_budget_bytes
 
 
 class Conv2dTemplate(MatmulTemplate):
@@ -233,7 +242,22 @@ class Conv2dTemplate(MatmulTemplate):
         item = np.dtype(op.dtype).itemsize
         halo = (cfg["row_block"] * d["stride"] + d["kh"]) * d["w"] * d["cin"] * item
         return halo + _vmem_matmul_bytes(cfg["bm"], cfg["bn"], cfg["bk"], op.dtype) \
-            <= 0.9 * chip.vmem_bytes
+            <= chip.vmem_budget_bytes
+
+
+def _vmem_attention_bytes(rows: int, kv_rows: int, d: int, dtype,
+                          chip: hw.Chip) -> int:
+    """VMEM one flash-attention tile holds (`kernels/attention.py`): `rows`
+    query rows (block_q x the GQA group for the paged prefill kernel)
+    against `kv_rows` key rows.  The paged kernels read pool blocks no
+    taller than the template's smallest block_kv, so this bounds them too."""
+    item = np.dtype(dtype).itemsize
+    hd = max(d, chip.lane)
+    return (2 * 2 * rows * hd * item         # q and out blocks: 2 buffers
+            + 2 * 2 * kv_rows * hd * item    # k and v blocks: 2 buffers
+            + 2 * rows * chip.lane * 4       # running max + denominator
+            + rows * hd * 4                  # f32 output accumulator
+            + 2 * rows * max(kv_rows, chip.lane) * 4)  # f32 logits + probs
 
 
 class AttentionTemplate(Template):
@@ -269,16 +293,10 @@ class AttentionTemplate(Template):
 
     def validate(self, op: OpDesc, cfg: Config, chip: hw.Chip = hw.TPU_V5E) -> bool:
         d = op.d
-        item = np.dtype(op.dtype).itemsize
-        hd = max(d["d"], chip.lane)
-        need = (
-            2 * cfg["block_q"] * hd * item          # q block (double buffered)
-            + 4 * cfg["block_kv"] * hd * item       # k + v blocks
-            + cfg["block_q"] * cfg["block_kv"] * 4  # logits f32
-            + cfg["block_q"] * hd * 4               # o accumulator f32
-            + 2 * cfg["block_q"] * 4 * chip.lane    # m/l running stats
-        )
-        return need <= 0.9 * chip.vmem_bytes
+        rows = cfg["block_q"] * d.get("g", 1)
+        need = _vmem_attention_bytes(rows, cfg["block_kv"], d["d"], op.dtype,
+                                     chip)
+        return need <= chip.vmem_budget_bytes
 
 
 TEMPLATES: Dict[str, Template] = {

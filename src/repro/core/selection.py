@@ -56,7 +56,8 @@ def op_desc_of(graph: Graph, node: Node, dtype: str = "bfloat16") -> Optional[Op
         q = graph.tensors[node.inputs[0]].shape
         k = graph.tensors[node.inputs[1]].shape
         b, qlen, heads, hd = q
-        return OpDesc.attention(b, qlen, k[1], heads, hd, dtype=dtype, label=node.name)
+        return OpDesc.attention(b, qlen, k[1], heads, hd, dtype=dtype,
+                                label=node.name, kv_heads=k[2])
     return None
 
 

@@ -1,4 +1,4 @@
-"""Hardware model for the target accelerator (TPU v5e-class chip).
+"""Hardware model of the TPU chips the system targets.
 
 Every hardware-aware decision in WPK (search fitness, constraint checking,
 roofline analysis, backend selection) reads from this single module so that
@@ -9,10 +9,16 @@ Numbers are the ones mandated for the roofline analysis:
   * 819 GB/s HBM bandwidth per chip
   * ~50 GB/s per ICI link
 plus micro-architectural facts needed by the kernel schedule templates:
-  * VMEM is ~128 MiB per core; a kernel's working set (all live BlockSpec
-    blocks, double-buffered) must fit.
+  * VMEM is 128 MiB per v5e core.  A kernel's working set (every block the
+    Pallas pipeline holds, double-buffered, plus its scratch) must fit in
+    `vmem_budget_bytes`, which every `pallas_call` also hands the compiler
+    as `vmem_limit_bytes`; the rest of VMEM is headroom for the compiler's
+    own temporaries.
   * The MXU is a 128x128 systolic array; sublane tiling is (8, 128) for f32
     and (16, 128) for bf16 — block dims should be multiples of these.
+
+`chip_for_kind` maps the `device_kind` a JAX device reports to its `Chip`;
+an unknown kind is an error, never a default.
 """
 
 from __future__ import annotations
@@ -35,6 +41,8 @@ class Chip:
     hbm_bytes: int = 16 * 1024**3
     hbm_bw: float = 819e9  # B/s
     vmem_bytes: int = 128 * 1024**2
+    # what one kernel may use: 3/4 of VMEM, the rest left to the compiler
+    vmem_budget_bytes: int = 96 * 1024**2
     # Interconnect
     ici_link_bw: float = 50e9  # B/s per link per direction
     ici_links_per_axis: int = 1  # conservative: 1 usable link per mesh axis
@@ -71,11 +79,33 @@ TPU_V5P = Chip(
     peak_f32_flops=114.75e12,
     hbm_bytes=95 * 1024**3,
     hbm_bw=2765e9,
-    vmem_bytes=128 * 1024**2,
+    vmem_bytes=64 * 1024**2,
+    vmem_budget_bytes=48 * 1024**2,
     ici_link_bw=100e9,
 )
 
 CHIPS = {"tpu_v5e": TPU_V5E, "tpu_v5p": TPU_V5P}
+
+# `device_kind` as JAX reports it -> (chip, published source of its numbers).
+# Peaks and HBM: Google Cloud TPU documentation, "TPU v5e" and "TPU v5p".
+# VMEM per TensorCore: the Pallas TPU hardware table,
+# `jax.experimental.pallas.tpu.get_tpu_info` (JAX 0.9.0).
+DEVICE_KINDS = {
+    "TPU v5 lite": (TPU_V5E, "cloud.google.com/tpu/docs/v5e; "
+                             "pallas.tpu.get_tpu_info"),
+    "TPU v5": (TPU_V5P, "cloud.google.com/tpu/docs/v5p; "
+                        "pallas.tpu.get_tpu_info"),
+}
+
+
+def chip_for_kind(device_kind: str) -> Chip:
+    """The `Chip` of a device by its `device_kind`; ValueError if unknown."""
+    try:
+        return DEVICE_KINDS[device_kind][0]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_KINDS)}") from None
 
 
 def align_up(x: int, m: int) -> int:

@@ -3,9 +3,9 @@
 The paper's compute hot-spots are exactly these: convolution (its headline
 benchmark), the matmul family, and fused operators produced by graph fusion.
 Each kernel module pairs with `ref.py` (pure-jnp oracle) and is exposed via
-`ops.py` (jit-friendly wrappers with tuned-config dispatch).  On this
-CPU-only container all kernels run in interpret mode; on TPU
-`interpret=False` compiles them natively with the tuned BlockSpec tiling.
+`ops.py` (jit-friendly wrappers with tuned-config dispatch).  On a TPU the
+kernels compile natively with the tuned BlockSpec tiling; on any other
+backend they run in the Pallas interpreter (`tpu.py` decides).
 """
 
 from repro.kernels import ops, ref

@@ -38,14 +38,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import pltpu, vmem_scratch
+from repro.kernels import tpu
 
 NEG_INF = -1e30
 
 
 def _scratch(shape, dtype=jnp.float32):
-    return vmem_scratch(shape, dtype)
+    return pltpu.VMEM(shape, dtype)
 
 
 # ---------------------------------------------------------------- prefill
@@ -105,7 +106,6 @@ def flash_attention_padded(
     causal: bool = True,
     scale: Optional[float] = None,
     q_per_kv: int = 1,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     bh, sq, d = q.shape
     _, skv, _ = k.shape
@@ -131,7 +131,7 @@ def flash_attention_padded(
             _scratch((block_q, 1)),
             _scratch((block_q, d)),
         ],
-        interpret=interpret,
+        **tpu.pallas_call_args(),
     )(q, k, v)
 
 
@@ -300,7 +300,6 @@ def flash_prefill_paged(
     *,
     block_q: Optional[int] = None,
     scale: Optional[float] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Block-table-aware segment-packed prefill flash attention.
 
@@ -319,8 +318,6 @@ def flash_prefill_paged(
     the schedule knob the plan's `prefill_chunk` stage tunes — together
     with the segment axis it defines the kernel's block_q x max-segments
     grid."""
-    if pltpu is None:  # pragma: no cover - no TPU pallas module at all
-        raise NotImplementedError("paged prefill kernel needs pallas TPU")
     c, g, d = q.shape
     _, block_size, _ = k_pool.shape
     ns, nbt = seg_tables.shape
@@ -356,7 +353,7 @@ def flash_prefill_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c_pad * g, d), q.dtype),
-        interpret=interpret,
+        **tpu.pallas_call_args(),
     )(seg_info.astype(jnp.int32), seg_tables.astype(jnp.int32),
       qf, k_pool, v_pool)
     return out.reshape(c_pad, g, d)[:c]
@@ -370,13 +367,10 @@ def flash_decode_paged(
     block_tables: jnp.ndarray,  # (B, nbt) int32 physical block ids
     *,
     scale: Optional[float] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Block-table-aware flash decode: the grid walks each slot's *logical*
     blocks and the scalar-prefetched table indirects to physical pool blocks,
     so the kernel never materialises a gathered contiguous cache."""
-    if pltpu is None:  # pragma: no cover - no TPU pallas module at all
-        raise NotImplementedError("paged decode kernel needs pallas TPU")
     b, h, d = q.shape
     _, block_size, _ = k_pool.shape
     nbt = block_tables.shape[1]
@@ -406,7 +400,7 @@ def flash_decode_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
+        **tpu.pallas_call_args(),
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), q,
       k_pool, v_pool)
 
@@ -419,7 +413,6 @@ def flash_decode_padded(
     *,
     block_kv: int = 512,
     scale: Optional[float] = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     b, h, d = q.shape
     _, skv, _ = k.shape
@@ -445,5 +438,5 @@ def flash_decode_padded(
             _scratch((h, 1)),
             _scratch((h, d)),
         ],
-        interpret=interpret,
+        **tpu.pallas_call_args(),
     )(lengths2d, q, k, v)

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tpu
 from repro.kernels.ref import apply_activation
 
 
@@ -68,7 +69,6 @@ def conv2d_direct(
     row_block: int = 4,
     activation: Optional[str] = None,
     out_dtype=None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     n, hp, wp, cin = x.shape
     kh, kw, _, cout = w.shape
@@ -112,7 +112,7 @@ def conv2d_direct(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, row_block, ow, bn), lambda nn, i, j: (nn, i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, oh_p, ow, cout_p), out_dtype),
-        interpret=interpret,
+        **tpu.pallas_call_args(),
     )(*args)
     return out[:, :oh, :, :cout]
 

@@ -5,7 +5,7 @@ The WPK plan records, per stage-qualified operator, WHICH lane won the race
 module is the serve-time bridge that makes those choices executable:
 
   * a **lane registry** mapping a backend name to a callable with the
-    uniform signature ``lane(x, w, *, config, activation, interpret)`` —
+    uniform signature ``lane(x, w, *, config, activation)`` —
     `xla` lowers through a plain einsum/`@` (the vendor-library lane) and
     `pallas_matmul` through the tuned `ops.matmul` kernel, with the
     activation fused into the kernel epilogue where the template supports
@@ -66,58 +66,46 @@ def lanes() -> Dict[str, LaneFn]:
 
 @register_lane("xla")
 def xla_lane(x: jnp.ndarray, w: jnp.ndarray, *, config: Optional[Dict] = None,
-             activation: Optional[str] = None,
-             interpret: bool = True) -> jnp.ndarray:
+             activation: Optional[str] = None) -> jnp.ndarray:
     """Vendor-library lane: plain XLA dot (+ unfused activation)."""
-    del config, interpret
+    del config
     return apply_activation(x @ w, activation)
 
 
 @register_lane("pallas_matmul")
 def pallas_matmul_lane(x: jnp.ndarray, w: jnp.ndarray, *,
                        config: Optional[Dict] = None,
-                       activation: Optional[str] = None,
-                       interpret: bool = True) -> jnp.ndarray:
+                       activation: Optional[str] = None) -> jnp.ndarray:
     """Tuned lane: the Pallas MXU matmul with the searched schedule config;
     the activation (when the role carries one) runs in the kernel epilogue."""
     from repro.kernels import ops  # lazy: keep kernel imports off hot import paths
 
-    return ops.matmul(x, w, config=config, activation=activation,
-                      interpret=interpret)
+    return ops.matmul(x, w, config=config, activation=activation)
 
 
 # ----------------------------------------------------------------- context
 _tls = threading.local()
 
 
-class _DispatchCtx:
-    __slots__ = ("table", "interpret")
-
-    def __init__(self, table: MatmulTable, interpret: bool):
-        self.table = table
-        self.interpret = interpret
-
-
 @contextlib.contextmanager
-def matmul_dispatch(table: Optional[MatmulTable], interpret: bool = True):
+def matmul_dispatch(table: Optional[MatmulTable]):
     """Install a per-stage matmul dispatch table for the enclosed trace.
 
     Like `sharding.activation_rules`, this is consulted while jit TRACES the
     program, so the table must be installed around the traced body (the
     `repro.launch.steps` builders do this) and its choices become static
     properties of the compiled program."""
-    prev = getattr(_tls, "ctx", None)
-    _tls.ctx = _DispatchCtx(dict(table or {}), interpret)
+    prev = getattr(_tls, "table", None)
+    _tls.table = dict(table or {})
     try:
         yield
     finally:
-        _tls.ctx = prev
+        _tls.table = prev
 
 
 def active_table() -> Optional[MatmulTable]:
     """The currently installed table (None outside a dispatch context)."""
-    ctx = getattr(_tls, "ctx", None)
-    return None if ctx is None else ctx.table
+    return getattr(_tls, "table", None)
 
 
 def dispatch_dense(role: Optional[str], x: jnp.ndarray, w: jnp.ndarray,
@@ -126,14 +114,13 @@ def dispatch_dense(role: Optional[str], x: jnp.ndarray, w: jnp.ndarray,
 
     Outside a dispatch context — or for a role the table does not name —
     this is the XLA lane, i.e. exactly `x @ w` (+ activation)."""
-    ctx = getattr(_tls, "ctx", None)
-    if ctx is None or role is None:
+    table = getattr(_tls, "table", None)
+    if table is None or role is None:
         return xla_lane(x, w, activation=activation)
-    backend, config = ctx.table.get(role, ("xla", {}))
+    backend, config = table.get(role, ("xla", {}))
     lane = _LANES.get(backend)
     if lane is None:
         raise KeyError(
             f"plan chose unknown matmul backend {backend!r} for role "
             f"{role!r}; registered lanes: {sorted(_LANES)}")
-    return lane(x, w, config=config, activation=activation,
-                interpret=ctx.interpret)
+    return lane(x, w, config=config, activation=activation)

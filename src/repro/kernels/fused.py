@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tpu
 from repro.kernels.ref import apply_activation
 
 _BINARY = {"add": jnp.add, "mul": jnp.multiply, "sub": jnp.subtract, "div": jnp.divide}
@@ -43,7 +44,6 @@ def fused_elementwise(
     extras: Sequence[jnp.ndarray] = (),
     *,
     block_rows: int = 256,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Apply `chain` to x in one kernel.  All extras must be broadcastable to
     x's shape; we require same-shape here (the fusion pass guarantees it)."""
@@ -76,6 +76,6 @@ def fused_elementwise(
         in_specs=[spec] * (1 + len(extras2)),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret,
+        **tpu.pallas_call_args(),
     )(x2, *extras2)
     return out.reshape(-1)[:n].reshape(orig_shape)

@@ -23,15 +23,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params, vmem_scratch
+from repro.kernels import tpu
 from repro.kernels.ref import apply_activation
-
-
-def _compiler_params(order):
-    # TPU compiler params are advisory; interpret mode ignores them.
-    sem = ("parallel", "parallel", "arbitrary")
-    return tpu_compiler_params(dimension_semantics=sem)
 
 
 def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, kt: int,
@@ -66,7 +61,6 @@ def matmul_padded(
     k_unroll: int = 1,       # schedule hint; no effect on semantics
     activation: Optional[str] = None,
     out_dtype=None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     m, k = x.shape
     _, n = w.shape
@@ -99,24 +93,15 @@ def matmul_padded(
         _matmul_kernel if bias is not None else _matmul_nobias_kernel,
         kt=kt, activation=activation, out_dtype=out_dtype,
     )
-    kwargs = {}
-    params = _compiler_params(order)
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), omap),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[_acc_scratch(bm, bn)],
-        interpret=interpret,
-        **kwargs,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        **tpu.pallas_call_args(("parallel", "parallel", "arbitrary")),
     )(*args)
-
-
-def _acc_scratch(bm: int, bn: int):
-    return vmem_scratch((bm, bn), jnp.float32)
 
 
 def _matmul_nobias_kernel(x_ref, w_ref, o_ref, acc_ref, *, kt, activation, out_dtype):

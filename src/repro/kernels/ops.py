@@ -8,7 +8,6 @@ wrapper:
   * handles padding/reshaping so the kernels only ever see block-aligned
     shapes (zero K/KV padding is mathematically inert; M/N padding is sliced
     off),
-  * exposes `interpret=` — True on this CPU container, False on real TPU,
   * falls back to the XLA lowering where a kernel is out of its envelope
     (e.g. image too large for whole-image VMEM residency in conv2d_direct).
 """
@@ -57,7 +56,6 @@ def matmul(
     config: Config = None,
     activation: Optional[str] = None,
     out_dtype=None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     cfg = dict(_DEF_MM, **(config or {}))
     lead = x.shape[:-1]
@@ -75,7 +73,7 @@ def matmul(
     out = matmul_padded(
         x2, w2, b2, bm=bm, bn=bn, bk=bk, order=cfg.get("order", "mn"),
         k_unroll=cfg.get("k_unroll", 1), activation=activation,
-        out_dtype=out_dtype or x.dtype, interpret=interpret)
+        out_dtype=out_dtype or x.dtype)
     return out[:m, :n].reshape(*lead, n)
 
 
@@ -89,7 +87,6 @@ def conv2d(
     layout: str = "NHWC",
     activation: Optional[str] = None,
     config: Config = None,
-    interpret: bool = True,
     vmem_limit: int = 64 * 1024 * 1024,
 ) -> jnp.ndarray:
     cfg = {**_DEF_MM, "row_block": 4, **(config or {})}
@@ -112,7 +109,7 @@ def conv2d(
         out = conv2d_direct(
             x, w, bias.reshape(1, -1) if bias is not None else None,
             stride=stride, bn=cfg["bn"], row_block=cfg.get("row_block", 4),
-            activation=activation, interpret=interpret)
+            activation=activation)
     else:
         # Fallback: XLA patch extraction + tuned Pallas GEMM.
         patches = jax.lax.conv_general_dilated_patches(
@@ -123,7 +120,7 @@ def conv2d(
         out = matmul(
             patches.reshape(-1, pc),
             w.transpose(2, 0, 1, 3).reshape(pc, cout),
-            bias, config=cfg, activation=activation, interpret=interpret,
+            bias, config=cfg, activation=activation,
         ).reshape(po, ph, pw_, cout)
 
     if layout == "NCHW":
@@ -139,7 +136,6 @@ def attention(
     causal: bool = True,
     scale: Optional[float] = None,
     config: Config = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     cfg = dict(_DEF_ATT, **(config or {}))
     b, sq, h, d = q.shape
@@ -164,7 +160,7 @@ def attention(
         return _ref.attention_ref(q, k, v, causal=causal, scale=scale)
     out = flash_attention_padded(
         qf, kf, vf, block_q=bq, block_kv=bkv, causal=causal, scale=scale,
-        q_per_kv=q_per_kv, interpret=interpret)
+        q_per_kv=q_per_kv)
     out = out[:, :sq].reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return out
 
@@ -177,7 +173,6 @@ def attention_decode(
     *,
     scale: Optional[float] = None,
     config: Config = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     cfg = dict(_DEF_ATT, **(config or {}))
     b, h, d = q.shape
@@ -191,7 +186,7 @@ def attention_decode(
         kg = _pad_to(k_cache[:, :, g], 1, bkv)          # (B, S_p, D)
         vg = _pad_to(v_cache[:, :, g], 1, bkv)
         outs.append(flash_decode_padded(qg, kg, vg, lengths, block_kv=bkv,
-                                        scale=scale, interpret=interpret))
+                                        scale=scale))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -204,7 +199,6 @@ def attention_decode_paged(
     *,
     scale: Optional[float] = None,
     config: Config = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Paged decode attention over the block pool (continuous-batching lane).
 
@@ -224,7 +218,7 @@ def attention_decode_paged(
         kg = k_pool[:, :, g]                            # (nb, bs, D)
         vg = v_pool[:, :, g]
         outs.append(flash_decode_paged(qg, kg, vg, lengths, block_tables,
-                                       scale=scale, interpret=interpret))
+                                       scale=scale))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -237,7 +231,6 @@ def attention_prefill_packed(
     *,
     scale: Optional[float] = None,
     config: Config = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Segment-packed paged prefill attention over the block pool (the
     prefill lane of the unified serving step).
@@ -263,7 +256,7 @@ def attention_prefill_packed(
         qg = q[0, :, g * group: (g + 1) * group]        # (C, group, D)
         outs.append(flash_prefill_paged(
             qg, k_pool[:, :, g], v_pool[:, :, g], seg_tables, info,
-            block_q=bq, scale=scale, interpret=interpret))
+            block_q=bq, scale=scale))
     return jnp.concatenate(outs, axis=1)[None]          # (1, C, H, D)
 
 
@@ -277,7 +270,6 @@ def attention_prefill_paged(
     *,
     scale: Optional[float] = None,
     config: Config = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Single-request chunked prefill — the S=1 special case of
     `attention_prefill_packed` (kept as the stable entry point for callers
@@ -287,7 +279,7 @@ def attention_prefill_paged(
                           jnp.asarray(chunk_start, jnp.int32)])[None]
     return attention_prefill_packed(
         q, k_pool, v_pool, block_tables, seg_info,
-        scale=scale, config=config, interpret=interpret)
+        scale=scale, config=config)
 
 
 def fused_elementwise(
@@ -296,9 +288,7 @@ def fused_elementwise(
     extras: Sequence[jnp.ndarray] = (),
     *,
     config: Config = None,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     cfg = config or {}
     return _fused_elementwise(x, chain, extras,
-                              block_rows=cfg.get("block_rows", 256),
-                              interpret=interpret)
+                              block_rows=cfg.get("block_rows", 256))

@@ -296,8 +296,7 @@ def paged_pool_sharding(model, mesh: Mesh, rules: ShardingRules):
 def jit_unified_step(model, mesh: Mesh, rules: ShardingRules,
                      decode_attn_backend: str = "xla",
                      chunk_attn_backend: str = "xla", chunk_attn_config=None,
-                     decode_matmul_table=None, chunk_matmul_table=None,
-                     interpret: bool = True):
+                     decode_matmul_table=None, chunk_matmul_table=None):
     """(params, k_pool, v_pool,
         dec_tables, dec_lengths, dec_tokens,   # decode lane: every slot
         ch_tokens, seg_tables, seg_info,       # prefill lane: packed chunk
@@ -350,19 +349,18 @@ def jit_unified_step(model, mesh: Mesh, rules: ShardingRules,
         with activation_rules(rules):
             # prefill lane: a packed chunk of prompt segments, K/V committed
             # to each segment's blocks in-program (no separate commit)
-            with matmul_dispatch(chunk_matmul_table, interpret=interpret):
+            with matmul_dispatch(chunk_matmul_table):
                 ch_logits, k_pool, v_pool = model.prefill_packed_paged(
                     params, k_pool, v_pool, seg_tables, ch_tokens,
                     seg_info, attn_backend=chunk_attn_backend,
-                    attn_config=chunk_attn_config, attn_interpret=interpret)
+                    attn_config=chunk_attn_config)
             # decode lane: one token for every slot (the lanes touch
             # disjoint blocks — a request never prefills and decodes in the
             # same step — so XLA is free to schedule them together)
-            with matmul_dispatch(decode_matmul_table, interpret=interpret):
+            with matmul_dispatch(decode_matmul_table):
                 logits, k_pool, v_pool = model.decode_step_paged(
                     params, k_pool, v_pool, dec_tables, dec_lengths,
-                    dec_tokens, attn_backend=decode_attn_backend,
-                    attn_interpret=interpret)
+                    dec_tokens, attn_backend=decode_attn_backend)
         # keyed sampling fused for all lanes: seg_next[s] is the first
         # token of segment s's request, valid only when that segment
         # completes its prompt (the host consumes it exactly then)
@@ -381,7 +379,7 @@ def jit_unified_step(model, mesh: Mesh, rules: ShardingRules,
 
 def jit_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
                          decode_attn_backend: str = "xla",
-                         decode_matmul_table=None, interpret: bool = True):
+                         decode_matmul_table=None):
     """(params, k_pool, v_pool, dec_tables, dec_lengths, dec_tokens,
         dec_sampling, dec_keys)
         -> (dec_next (slots,), k_pool, v_pool)
@@ -408,11 +406,10 @@ def jit_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
     def decode_only_step(params, k_pool, v_pool, dec_tables, dec_lengths,
                          dec_tokens, dec_sampling, dec_keys):
         with activation_rules(rules):
-            with matmul_dispatch(decode_matmul_table, interpret=interpret):
+            with matmul_dispatch(decode_matmul_table):
                 logits, k_pool, v_pool = model.decode_step_paged(
                     params, k_pool, v_pool, dec_tables, dec_lengths,
-                    dec_tokens, attn_backend=decode_attn_backend,
-                    attn_interpret=interpret)
+                    dec_tokens, attn_backend=decode_attn_backend)
         nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
         return nxt, k_pool, v_pool
 
@@ -504,8 +501,7 @@ def slot_state_shardings(model, mesh: Mesh, rules: ShardingRules):
 
 
 def jit_ssm_unified_step(model, mesh: Mesh, rules: ShardingRules,
-                         decode_matmul_table=None, chunk_matmul_table=None,
-                         interpret: bool = True):
+                         decode_matmul_table=None, chunk_matmul_table=None):
     """(params, conv_pool, ssm_pool,
         dec_state_idx, dec_tokens,                # decode lane: every slot
         ch_tokens, ch_state_idx, ch_seg_len, ch_seg_start,  # prefill lane
@@ -540,12 +536,12 @@ def jit_ssm_unified_step(model, mesh: Mesh, rules: ShardingRules,
                          ch_keys):
         with activation_rules(rules):
             # prefill lane: one prompt segment, state committed in-program
-            with matmul_dispatch(chunk_matmul_table, interpret=interpret):
+            with matmul_dispatch(chunk_matmul_table):
                 ch_logits, conv_pool, ssm_pool = model.prefill_chunk_slots(
                     params, conv_pool, ssm_pool, ch_state_idx, ch_tokens,
                     ch_seg_len, ch_seg_start)
             # decode lane: one token for every slot
-            with matmul_dispatch(decode_matmul_table, interpret=interpret):
+            with matmul_dispatch(decode_matmul_table):
                 logits, conv_pool, ssm_pool = model.decode_step_slots(
                     params, conv_pool, ssm_pool, dec_state_idx, dec_tokens)
         nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
@@ -562,8 +558,7 @@ def jit_ssm_unified_step(model, mesh: Mesh, rules: ShardingRules,
 
 
 def jit_ssm_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
-                             decode_matmul_table=None,
-                             interpret: bool = True):
+                             decode_matmul_table=None):
     """(params, conv_pool, ssm_pool, dec_state_idx, dec_tokens,
         dec_sampling, dec_keys)
         -> (dec_next (slots,), conv_pool, ssm_pool)
@@ -583,7 +578,7 @@ def jit_ssm_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
     def ssm_decode_only_step(params, conv_pool, ssm_pool, dec_state_idx,
                              dec_tokens, dec_sampling, dec_keys):
         with activation_rules(rules):
-            with matmul_dispatch(decode_matmul_table, interpret=interpret):
+            with matmul_dispatch(decode_matmul_table):
                 logits, conv_pool, ssm_pool = model.decode_step_slots(
                     params, conv_pool, ssm_pool, dec_state_idx, dec_tokens)
         nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)

@@ -180,7 +180,6 @@ def attn_forward(
     return_kv: bool = False,
     backend: str = "xla",                   # 'pallas*' -> tuned flash kernel
     backend_config: Optional[Dict[str, Any]] = None,
-    interpret: bool = True,
 ):
     b, s, _ = x.shape
     if kv_override is not None:             # cross-attention
@@ -194,8 +193,7 @@ def attn_forward(
         q, k, v = _project_qkv(p, cfg, x, positions)
     if backend.startswith("pallas") and kv_override is None:
         from repro.kernels import ops as K
-        out = K.attention(q, k, v, causal=causal, config=backend_config,
-                          interpret=interpret)
+        out = K.attention(q, k, v, causal=causal, config=backend_config)
     else:
         out = _sdpa(q, k, v, causal, cfg.q_per_kv)
     out = constrain(out, ("batch", None, "heads", None))
@@ -302,7 +300,6 @@ def attn_prefill_packed(
     *,
     backend: str = "xla",
     backend_config=None,
-    interpret: bool = True,
 ):
     """Segment-packed prefill attention against the *paged* KV pool.
 
@@ -339,7 +336,7 @@ def attn_prefill_packed(
         from repro.kernels import ops as K
         out = K.attention_prefill_packed(
             q, k_pool, v_pool, seg_tables, seg_info,
-            config=backend_config, interpret=interpret)
+            config=backend_config)
     else:
         # XLA lane: gather each ROW's logical cache view from the pool via
         # its segment's table.  The gather width is always the full table
@@ -374,7 +371,6 @@ def attn_decode_paged(
     lengths: jnp.ndarray,           # (B,) current context lengths (new token pos)
     *,
     backend: str = "xla",
-    interpret: bool = True,         # False compiles the kernel on real TPU
 ):
     """Decode attention against the *paged* KV pool.
 
@@ -402,7 +398,7 @@ def attn_decode_paged(
         from repro.kernels import ops as K
         out = K.attention_decode_paged(
             q.reshape(b, cfg.n_heads, cfg.hd), k_pool, v_pool,
-            lengths + 1, block_tables, interpret=interpret)
+            lengths + 1, block_tables)
         out = out.reshape(b, hkv, g, cfg.hd)
     else:
         # XLA lane: gather each slot's logical cache view from the pool.

@@ -276,8 +276,7 @@ class DecoderLM:
     def decode_step_paged(self, params: Params, k_pool: jnp.ndarray,
                           v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                           lengths: jnp.ndarray, tokens: jnp.ndarray,
-                          *, attn_backend: str = "xla",
-                          attn_interpret: bool = True):
+                          *, attn_backend: str = "xla"):
         """One decode step over the slot batch against the paged KV pool.
 
         k_pool/v_pool: (L, num_blocks, block_size, Hkv, hd); tokens: (B, 1).
@@ -292,7 +291,7 @@ class DecoderLM:
             h = _norm(cfg, bp["attn_norm"], x)
             y, kp, vp = A.attn_decode_paged(
                 bp["attn"], cfg, h, kp, vp, block_tables, lengths,
-                backend=attn_backend, interpret=attn_interpret)
+                backend=attn_backend)
             x = x + y
             h = _norm(cfg, bp["mlp_norm"], x)
             if cfg.family == "moe":
@@ -310,8 +309,7 @@ class DecoderLM:
                              v_pool: jnp.ndarray, seg_tables: jnp.ndarray,
                              tokens: jnp.ndarray, seg_info: jnp.ndarray,
                              *, attn_backend: str = "xla",
-                             attn_config: Optional[Dict[str, Any]] = None,
-                             attn_interpret: bool = True):
+                             attn_config: Optional[Dict[str, Any]] = None):
         """A segment-packed prompt chunk against the paged KV pool — the
         prefill lane of the unified serving step.
 
@@ -342,7 +340,7 @@ class DecoderLM:
             y, kp, vp = A.attn_prefill_packed(
                 bp["attn"], cfg, h, kp, vp, seg_tables, positions,
                 seg_info, backend=attn_backend,
-                backend_config=attn_config, interpret=attn_interpret)
+                backend_config=attn_config)
             x = x + y
             h = _norm(cfg, bp["mlp_norm"], x)
             if cfg.family == "moe":

@@ -21,6 +21,12 @@ or explicitly: `platform.set_host_device_count(4)`.
 Setting the flag after jax has initialized cannot work, so that case warns
 and leaves the environment alone rather than silently lying about the
 device count the process will actually see.
+
+`configure_compile_cache()` gives JAX's persistent compilation cache its one
+directory: `JAX_COMPILATION_CACHE_DIR` where it is set (JAX reads it itself),
+and otherwise `.jax_cache/` at the root of the checkout.  The path is part
+of the cache's key, so it is fixed: never a temporary name, a process id or
+the time.
 """
 
 from __future__ import annotations
@@ -29,9 +35,12 @@ import os
 import re
 import sys
 import warnings
+from pathlib import Path
 from typing import List, Optional
 
 _FLAG = "--xla_force_host_platform_device_count"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def _jax_initialized() -> bool:
@@ -92,3 +101,17 @@ def configure_from_argv(argv: Optional[List[str]] = None) -> Optional[int]:
     if n is not None:
         set_host_device_count(n)
     return n
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory; returns it.
+
+    With `JAX_COMPILATION_CACHE_DIR` set nothing is changed: JAX already
+    keeps its cache there.  Otherwise the cache goes to `CHECKOUT_CACHE`."""
+    import jax
+
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = str(CHECKOUT_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

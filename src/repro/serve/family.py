@@ -149,13 +149,11 @@ class DecoderFamilyAdapter:
             chunk_attn_backend=chunk_backend,
             chunk_attn_config=chunk_config,
             decode_matmul_table=router.matmul_table("decode"),
-            chunk_matmul_table=router.matmul_table("prefill_chunk"),
-            interpret=cfg.interpret)
+            chunk_matmul_table=router.matmul_table("prefill_chunk"))
         self._decode_only = jit_decode_only_step(
             model, mesh, rules,
             decode_attn_backend=decode_backend,
-            decode_matmul_table=router.matmul_table("decode"),
-            interpret=cfg.interpret)
+            decode_matmul_table=router.matmul_table("decode"))
         # resume-only commit (swap-in scatter): single full-width shape
         # carrying up to `resume_segments` requests per invocation, padded
         # segments diverted to the null sink
@@ -352,12 +350,10 @@ class SSMFamilyAdapter:
         self._unified = jit_ssm_unified_step(
             model, mesh, rules,
             decode_matmul_table=router.matmul_table(decode_stage),
-            chunk_matmul_table=router.matmul_table(chunk_stage),
-            interpret=cfg.interpret)
+            chunk_matmul_table=router.matmul_table(chunk_stage))
         self._decode_only = jit_ssm_decode_only_step(
             model, mesh, rules,
-            decode_matmul_table=router.matmul_table(decode_stage),
-            interpret=cfg.interpret)
+            decode_matmul_table=router.matmul_table(decode_stage))
         self._commit = jit_ssm_commit_state(model, mesh, rules)
 
     # ------------------------------------------------------------- capacity

@@ -166,7 +166,6 @@ class RuntimeConfig:
     # token.  Off by default — token streams are byte-identical either
     # way; the differential suite toggles it explicitly.
     prefix_sharing: bool = False
-    interpret: bool = True            # False: compile Pallas lanes on real TPU
 
     @property
     def max_seq(self) -> int:

@@ -92,7 +92,7 @@ def test_tuned_lane_matches_xla_lane_at_serve_shapes(name, xshape, n, act):
     w = jnp.asarray(rng.standard_normal((xshape[-1], n)), jnp.float32)
     ref = dispatch.xla_lane(x, w, activation=act)
     out = dispatch.pallas_matmul_lane(x, w, config=dict(MM_CFG),
-                                      activation=act, interpret=True)
+                                      activation=act)
     assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)

@@ -6,6 +6,7 @@ import json
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -13,7 +14,7 @@ import pytest
 
 from repro.configs import SHAPES, get_config
 from repro.distributed.sharding import DEFAULT_RULES, ShardingRules, prune_for_mesh
-from repro.launch.mesh import single_device_mesh
+from repro.launch.mesh import make_mesh, single_device_mesh, tp_mesh
 from repro.launch.steps import rules_for_shape, zero1_axes
 
 
@@ -81,7 +82,7 @@ def test_logical_to_spec_unknown_axis_raises():
 
 
 def test_rules_for_shape_divisibility_fallbacks():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         shape = {"data": 16, "model": 16}
@@ -149,6 +150,7 @@ _SUBPROC = textwrap.dedent("""
     import json
     import jax, numpy as np
     from repro.configs import get_config, input_specs, SHAPES
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import TrainConfig, jit_train_step, rules_for_shape
     from repro.models import build_model
     from repro.optim import adamw_init
@@ -156,7 +158,7 @@ _SUBPROC = textwrap.dedent("""
 
     cfg = get_config("qwen3-1.7b").reduced()
     shape = dataclasses.replace(SHAPES["train_4k"], seq_len=32, global_batch=8)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     model = build_model(cfg)
     rules = rules_for_shape(cfg, shape, mesh)
     with mesh:
@@ -194,9 +196,46 @@ def test_compressed_allgather_mean_roundtrip():
     from jax.sharding import PartitionSpec as P
     from repro.optim import compressed_allgather_mean
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     x = np.linspace(-1, 1, 64).astype(np.float32)
     fn = shard_map(partial(compressed_allgather_mean, axis_name="pod"),
                    mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
     got = np.asarray(fn(x))
     np.testing.assert_allclose(got, x, atol=2.0 / 127)
+
+
+@pytest.mark.parametrize("build", [
+    lambda: make_mesh((1, 1)),
+    lambda: make_mesh((1,), ("pod",)),
+    lambda: single_device_mesh(),
+    lambda: tp_mesh(1),
+], ids=["make_mesh", "make_mesh-1d", "single_device_mesh", "tp_mesh"])
+def test_every_mesh_has_auto_axes(build):
+    """launch/mesh.py decides axis types for the whole program: Auto on
+    every axis (jax.make_mesh's own default is Explicit).
+    make_production_mesh is make_mesh at (16, 16) / (2, 16, 16)."""
+    from jax.sharding import AxisType
+
+    mesh = build()
+    assert mesh.axis_types == (AxisType.Auto,) * len(mesh.axis_names)
+
+
+def test_compile_cache_dir_is_the_env_or_the_checkout(monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro import platform
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(platform.CACHE_ENV, "/somewhere/else")
+        assert platform.configure_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv(platform.CACHE_ENV)
+        path = platform.configure_compile_cache()
+        assert path == str(platform.CHECKOUT_CACHE)
+        assert platform.CHECKOUT_CACHE.parent == (
+            Path(__file__).resolve().parents[1])
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        cc.reset_cache()

@@ -40,7 +40,7 @@ def test_full_wpk_pipeline_equivalence(mini_convnet):
     g = mini_convnet
     gopt = optimize_graph(g)
     plan = select(gopt, tuner=Tuner(methods=("genetic",)), dtype="float32")
-    eng = Engine(gopt, plan, default_registry(interpret=True))
+    eng = Engine(gopt, plan, default_registry())
     x = jnp.asarray(np.random.default_rng(1).standard_normal((2, 3, 16, 16)).astype(np.float32))
     err = eng.verify_against_reference(x)
     ref = Engine(g, None, None)(x)[0]

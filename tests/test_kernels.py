@@ -1,5 +1,5 @@
 """Pallas-kernel correctness: shape/dtype sweeps against the pure-jnp
-oracles in repro.kernels.ref (interpret mode on CPU)."""
+oracles in repro.kernels.ref (the Pallas interpreter on CPU)."""
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +36,7 @@ def test_matmul_fused_epilogue(order, act):
     w = jnp.asarray(rng.standard_normal((256, 128)) * 0.1, jnp.float32)
     b = jnp.asarray(rng.standard_normal((1, 128)), jnp.float32)
     out = matmul_padded(x, w, b, bm=64, bn=128, bk=128, order=order,
-                        activation=act, interpret=True)
+                        activation=act)
     want = ref.matmul_ref(x, w, b[0], activation=act)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -121,3 +121,25 @@ def test_tuned_config_from_search_is_numerically_sound():
     out = kernels.matmul(x, w, config=res.config)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.matmul_ref(x, w)),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_interpret_exactly_off_tpu():
+    """The one backend decision: interpreted on the CPU, no compiler
+    params to hand a chip that is not there."""
+    from repro.kernels import tpu
+
+    assert jax.default_backend() == "cpu"
+    assert tpu.interpret() is True
+    assert tpu.pallas_call_args(("parallel",)) == {"interpret": True}
+
+
+def test_device_kind_lookup_names_the_chip_and_refuses_unknown_kinds():
+    from repro import hw
+
+    assert hw.chip_for_kind("TPU v5 lite") is hw.TPU_V5E
+    assert hw.chip_for_kind("TPU v5") is hw.TPU_V5P
+    for kind in ("cpu", "TPU v9", ""):
+        with pytest.raises(ValueError, match="no hardware model"):
+            hw.chip_for_kind(kind)
+    for chip, _source in hw.DEVICE_KINDS.values():
+        assert 0 < chip.vmem_budget_bytes < chip.vmem_bytes

@@ -130,3 +130,41 @@ def test_modeled_time_above_roofline(seed):
     tmpl = TEMPLATES["pallas_matmul"]
     cfg = tmpl.random_config(MM, rng)
     assert pallas_time(MM, cfg) >= roofline_bound(MM) * 0.9
+
+
+def test_vmem_model_counts_the_output_block_and_scratch():
+    """The matmul kernel's pipeline double-buffers A, B and the output
+    block and keeps an f32 accumulator: the model counts all of them."""
+    from repro.core.schedules import _vmem_matmul_bytes
+
+    bm, bn, bk, item = 512, 1024, 2048, 2
+    inputs = 2 * (bm * bk + bk * bn) * item
+    assert (_vmem_matmul_bytes(bm, bn, bk, "bfloat16")
+            >= inputs + 2 * bm * bn * item + bm * bn * 4)
+
+
+@pytest.mark.parametrize("tmpl_name,op,cfg", [
+    ("pallas_matmul", OpDesc.matmul(2048, 6144, 2048),
+     {"bm": 512, "bn": 1024, "bk": 2048, "order": "mn", "k_unroll": 1}),
+    ("pallas_attention", OpDesc.attention(1, 256, 1024, 16, 128,
+                                          kv_heads=8),
+     {"block_q": 256, "block_kv": 512}),
+])
+def test_validate_admits_exactly_the_chip_vmem_budget(tmpl_name, op, cfg):
+    """What the search admits is what every pallas_call asks of the
+    compiler: the chip's `vmem_budget_bytes`, to the byte."""
+    import dataclasses
+
+    tmpl = TEMPLATES[tmpl_name]
+    lo, hi = 0, 1 << 40
+    while lo < hi:  # smallest budget the template admits this config at
+        mid = (lo + hi) // 2
+        chip = dataclasses.replace(hw.TPU_V5E, vmem_budget_bytes=mid)
+        lo, hi = (lo, mid) if tmpl.validate(op, cfg, chip) else (mid + 1, hi)
+    need = lo
+    assert 0 < need <= hw.TPU_V5E.vmem_budget_bytes
+    assert not tmpl.validate(
+        op, cfg, dataclasses.replace(hw.TPU_V5E, vmem_budget_bytes=need - 1))
+    # the physical size plays no part: only the budget the compiler is told
+    assert tmpl.validate(op, cfg, dataclasses.replace(
+        hw.TPU_V5E, vmem_budget_bytes=need, vmem_bytes=need))
