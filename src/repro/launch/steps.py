@@ -337,6 +337,9 @@ def jit_unified_step(model, mesh: Mesh, rules: ShardingRules,
     per-stage matmul tables (the plan's `decode` and `prefill_chunk`
     stage choices) are closed over — static at trace time, zero per-step
     dispatch cost."""
+    # imported here: repro.serve imports this module
+    from repro.serve.trace import scope
+
     rules = prune_for_mesh(rules, mesh)
     p_shard, _ = make_state_shardings(model, mesh, rules, None)
     pool_shard = paged_pool_sharding(model, mesh, rules)
@@ -349,7 +352,7 @@ def jit_unified_step(model, mesh: Mesh, rules: ShardingRules,
         with activation_rules(rules):
             # prefill lane: a packed chunk of prompt segments, K/V committed
             # to each segment's blocks in-program (no separate commit)
-            with matmul_dispatch(chunk_matmul_table):
+            with matmul_dispatch(chunk_matmul_table), scope("prefill_lane"):
                 ch_logits, k_pool, v_pool = model.prefill_packed_paged(
                     params, k_pool, v_pool, seg_tables, ch_tokens,
                     seg_info, attn_backend=chunk_attn_backend,
@@ -357,15 +360,16 @@ def jit_unified_step(model, mesh: Mesh, rules: ShardingRules,
             # decode lane: one token for every slot (the lanes touch
             # disjoint blocks — a request never prefills and decodes in the
             # same step — so XLA is free to schedule them together)
-            with matmul_dispatch(decode_matmul_table):
+            with matmul_dispatch(decode_matmul_table), scope("decode_lane"):
                 logits, k_pool, v_pool = model.decode_step_paged(
                     params, k_pool, v_pool, dec_tables, dec_lengths,
                     dec_tokens, attn_backend=decode_attn_backend)
         # keyed sampling fused for all lanes: seg_next[s] is the first
         # token of segment s's request, valid only when that segment
         # completes its prompt (the host consumes it exactly then)
-        nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
-        seg_next = sample_tokens(ch_logits[0], seg_sampling, seg_keys)
+        with scope("sample"):
+            nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
+            seg_next = sample_tokens(ch_logits[0], seg_sampling, seg_keys)
         return nxt, seg_next, k_pool, v_pool
 
     return jax.jit(
@@ -397,6 +401,9 @@ def jit_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
     token streams.  With it the serving runtime owns exactly TWO step
     executables, chosen per step by whether prompt work exists; admission
     still compiles nothing."""
+    # imported here: repro.serve imports this module
+    from repro.serve.trace import scope
+
     rules = prune_for_mesh(rules, mesh)
     p_shard, _ = make_state_shardings(model, mesh, rules, None)
     pool_shard = paged_pool_sharding(model, mesh, rules)
@@ -406,11 +413,12 @@ def jit_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
     def decode_only_step(params, k_pool, v_pool, dec_tables, dec_lengths,
                          dec_tokens, dec_sampling, dec_keys):
         with activation_rules(rules):
-            with matmul_dispatch(decode_matmul_table):
+            with matmul_dispatch(decode_matmul_table), scope("decode_lane"):
                 logits, k_pool, v_pool = model.decode_step_paged(
                     params, k_pool, v_pool, dec_tables, dec_lengths,
                     dec_tokens, attn_backend=decode_attn_backend)
-        nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
+        with scope("sample"):
+            nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
         return nxt, k_pool, v_pool
 
     return jax.jit(
@@ -524,6 +532,9 @@ def jit_ssm_unified_step(model, mesh: Mesh, rules: ShardingRules,
     fused exactly as in the paged steps (`repro.kernels.sampling`): the
     per-slot / per-chunk sampling and key arrays are traced data, greedy
     rows reduce bitwise to the argmax path."""
+    # imported here: repro.serve imports this module
+    from repro.serve.trace import scope
+
     rules = prune_for_mesh(rules, mesh)
     p_shard, _ = make_state_shardings(model, mesh, rules, None)
     conv_shard, ssm_shard = slot_state_shardings(model, mesh, rules)
@@ -536,16 +547,18 @@ def jit_ssm_unified_step(model, mesh: Mesh, rules: ShardingRules,
                          ch_keys):
         with activation_rules(rules):
             # prefill lane: one prompt segment, state committed in-program
-            with matmul_dispatch(chunk_matmul_table):
+            with matmul_dispatch(chunk_matmul_table), scope("prefill_lane"):
                 ch_logits, conv_pool, ssm_pool = model.prefill_chunk_slots(
                     params, conv_pool, ssm_pool, ch_state_idx, ch_tokens,
                     ch_seg_len, ch_seg_start)
             # decode lane: one token for every slot
-            with matmul_dispatch(decode_matmul_table):
+            with matmul_dispatch(decode_matmul_table), scope("decode_lane"):
                 logits, conv_pool, ssm_pool = model.decode_step_slots(
                     params, conv_pool, ssm_pool, dec_state_idx, dec_tokens)
-        nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
-        ch_next = sample_tokens(ch_logits[:, -1], ch_sampling, ch_keys)[0]
+        with scope("sample"):
+            nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
+            ch_next = sample_tokens(ch_logits[:, -1], ch_sampling,
+                                    ch_keys)[0]
         return nxt, ch_next, conv_pool, ssm_pool
 
     return jax.jit(
@@ -569,6 +582,9 @@ def jit_ssm_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
     pools ping-pong between the two executables without a layout shift, and
     the decode lane's float program is identical — switching programs is
     invisible to the token streams."""
+    # imported here: repro.serve imports this module
+    from repro.serve.trace import scope
+
     rules = prune_for_mesh(rules, mesh)
     p_shard, _ = make_state_shardings(model, mesh, rules, None)
     conv_shard, ssm_shard = slot_state_shardings(model, mesh, rules)
@@ -578,10 +594,11 @@ def jit_ssm_decode_only_step(model, mesh: Mesh, rules: ShardingRules,
     def ssm_decode_only_step(params, conv_pool, ssm_pool, dec_state_idx,
                              dec_tokens, dec_sampling, dec_keys):
         with activation_rules(rules):
-            with matmul_dispatch(decode_matmul_table):
+            with matmul_dispatch(decode_matmul_table), scope("decode_lane"):
                 logits, conv_pool, ssm_pool = model.decode_step_slots(
                     params, conv_pool, ssm_pool, dec_state_idx, dec_tokens)
-        nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
+        with scope("sample"):
+            nxt = sample_tokens(logits[:, -1], dec_sampling, dec_keys)
         return nxt, conv_pool, ssm_pool
 
     return jax.jit(

@@ -31,6 +31,7 @@ from repro.models.common import (
     norm_init,
     rms_norm,
 )
+from repro.serve.trace import scope
 
 
 def attn_init(rng, cfg: ModelConfig, d_model: Optional[int] = None) -> Params:
@@ -64,26 +65,30 @@ def attn_logical_axes(cfg: ModelConfig) -> Params:
 
 def _project_qkv(p: Params, cfg: ModelConfig, x: jnp.ndarray,
                  positions: Optional[jnp.ndarray]):
-    b, s, _ = x.shape
-    hd = cfg.hd
-    # role-tagged: the serve plan's stage `qkv_proj` choice dispatches these
-    q = dense(p["wq"], x, role="qkv_proj").reshape(b, s, cfg.n_heads, hd)
-    k = dense(p["wk"], x, role="qkv_proj").reshape(b, s, cfg.n_kv_heads, hd)
-    v = dense(p["wv"], x, role="qkv_proj").reshape(b, s, cfg.n_kv_heads, hd)
-    if cfg.qk_norm:
-        q = rms_norm(p["q_norm"], q)
-        k = rms_norm(p["k_norm"], k)
-    if positions is not None:
-        if cfg.mrope_sections:
-            q = apply_mrope(q, positions, cfg.mrope_sections, cfg.rope_theta)
-            k = apply_mrope(k, positions, cfg.mrope_sections, cfg.rope_theta)
-        else:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
-    q = constrain(q, ("batch", None, "heads", None))
-    k = constrain(k, ("batch", None, "kv_heads", None))
-    v = constrain(v, ("batch", None, "kv_heads", None))
-    return q, k, v
+    # q/k norm and rotary positions belong to the projection's scope
+    with scope("qkv_proj"):
+        b, s, _ = x.shape
+        hd, hq, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
+        # role-tagged: the serve plan's stage `qkv_proj` choice dispatches
+        # these
+        q = dense(p["wq"], x, role="qkv_proj").reshape(b, s, hq, hd)
+        k = dense(p["wk"], x, role="qkv_proj").reshape(b, s, hkv, hd)
+        v = dense(p["wv"], x, role="qkv_proj").reshape(b, s, hkv, hd)
+        if cfg.qk_norm:
+            q = rms_norm(p["q_norm"], q)
+            k = rms_norm(p["k_norm"], k)
+        if positions is not None:
+            if cfg.mrope_sections:
+                sec = cfg.mrope_sections
+                q = apply_mrope(q, positions, sec, cfg.rope_theta)
+                k = apply_mrope(k, positions, sec, cfg.rope_theta)
+            else:
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
+        q = constrain(q, ("batch", None, "heads", None))
+        k = constrain(k, ("batch", None, "kv_heads", None))
+        v = constrain(v, ("batch", None, "kv_heads", None))
+        return q, k, v
 
 
 _CHUNKED_KV_THRESHOLD = 1024
@@ -323,40 +328,46 @@ def attn_prefill_packed(
     q, k_new, v_new = _project_qkv(p, cfg, x, positions)
 
     # incremental commit: each row scatters into its own segment's blocks
-    sid, pos, valid = packed_row_map(seg_info, c)
-    row_tables = jnp.asarray(seg_tables, jnp.int32)[sid]          # (C, nbt)
-    blk = row_tables[jnp.arange(c), jnp.clip(pos // block_size, 0, nbt - 1)]
-    blk = jnp.where(valid, blk, 0)                  # padding -> null sink
-    off = pos % block_size
-    k_pool = k_pool.at[blk, off].set(k_new[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v_new[0].astype(v_pool.dtype))
+    with scope("kv_write"):
+        sid, pos, valid = packed_row_map(seg_info, c)
+        row_tables = jnp.asarray(seg_tables, jnp.int32)[sid]      # (C, nbt)
+        blk = row_tables[jnp.arange(c),
+                         jnp.clip(pos // block_size, 0, nbt - 1)]
+        blk = jnp.where(valid, blk, 0)              # padding -> null sink
+        off = pos % block_size
+        k_pool = k_pool.at[blk, off].set(k_new[0].astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, off].set(v_new[0].astype(v_pool.dtype))
 
     hkv, g = cfg.n_kv_heads, cfg.q_per_kv
-    if backend.startswith("pallas"):
-        from repro.kernels import ops as K
-        out = K.attention_prefill_packed(
-            q, k_pool, v_pool, seg_tables, seg_info,
-            config=backend_config)
-    else:
-        # XLA lane: gather each ROW's logical cache view from the pool via
-        # its segment's table.  The gather width is always the full table
-        # (nbt * block_size) and the mask is purely positional, so the
-        # per-row float program is identical for every chunk split AND for
-        # every packing — chunked, unchunked, packed and single-segment
-        # prefill all agree bitwise on this lane.
-        k_ctx = k_pool[row_tables].reshape(b, c, nbt * block_size, hkv, cfg.hd)
-        v_ctx = v_pool[row_tables].reshape(b, c, nbt * block_size, hkv, cfg.hd)
-        scale = 1.0 / np.sqrt(cfg.hd)
-        qg = q.reshape(b, c, hkv, g, cfg.hd)
-        logits = jnp.einsum("bqhgd,bqkhd->bhgqk", qg,
-                            k_ctx).astype(jnp.float32) * scale
-        kpos = jnp.arange(nbt * block_size)[None, None, None, None, :]
-        logits = jnp.where(kpos <= pos[None, None, None, :, None],
-                           logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v_ctx.dtype)
-        out = jnp.einsum("bhgqk,bqkhd->bqhgd", probs,
-                         v_ctx).reshape(b, c, cfg.n_heads, cfg.hd)
-    y = dense(p["wo"], out.reshape(b, c, cfg.n_heads * cfg.hd))
+    with scope("attention"):
+        if backend.startswith("pallas"):
+            from repro.kernels import ops as K
+            out = K.attention_prefill_packed(
+                q, k_pool, v_pool, seg_tables, seg_info,
+                config=backend_config)
+        else:
+            # XLA lane: gather each ROW's logical cache view from the pool
+            # via its segment's table.  The gather width is always the full
+            # table (nbt * block_size) and the mask is purely positional, so
+            # the per-row float program is identical for every chunk split
+            # AND for every packing — chunked, unchunked, packed and
+            # single-segment prefill all agree bitwise on this lane.
+            k_ctx = k_pool[row_tables].reshape(b, c, nbt * block_size, hkv,
+                                               cfg.hd)
+            v_ctx = v_pool[row_tables].reshape(b, c, nbt * block_size, hkv,
+                                               cfg.hd)
+            scale = 1.0 / np.sqrt(cfg.hd)
+            qg = q.reshape(b, c, hkv, g, cfg.hd)
+            logits = jnp.einsum("bqhgd,bqkhd->bhgqk", qg,
+                                k_ctx).astype(jnp.float32) * scale
+            kpos = jnp.arange(nbt * block_size)[None, None, None, None, :]
+            logits = jnp.where(kpos <= pos[None, None, None, :, None],
+                               logits, -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(v_ctx.dtype)
+            out = jnp.einsum("bhgqk,bqkhd->bqhgd", probs,
+                             v_ctx).reshape(b, c, cfg.n_heads, cfg.hd)
+    with scope("o_proj"):
+        y = dense(p["wo"], out.reshape(b, c, cfg.n_heads * cfg.hd))
     return y, k_pool, v_pool
 
 
@@ -387,31 +398,37 @@ def attn_decode_paged(
         pos3 = jnp.broadcast_to(lengths[:, None, None], (b, 1, 3))
         q, k_new, v_new = _project_qkv(p, cfg, x, pos3)
 
-    bidx = jnp.arange(b)
-    blk = block_tables[bidx, lengths // block_size]     # (B,) physical block
-    off = lengths % block_size
-    k_pool = k_pool.at[blk, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v_new[:, 0].astype(v_pool.dtype))
+    with scope("kv_write"):
+        bidx = jnp.arange(b)
+        blk = block_tables[bidx, lengths // block_size]  # (B,) physical
+        off = lengths % block_size
+        k_pool = k_pool.at[blk, off].set(k_new[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, off].set(v_new[:, 0].astype(v_pool.dtype))
 
     hkv, g = cfg.n_kv_heads, cfg.q_per_kv
-    if backend.startswith("pallas"):
-        from repro.kernels import ops as K
-        out = K.attention_decode_paged(
-            q.reshape(b, cfg.n_heads, cfg.hd), k_pool, v_pool,
-            lengths + 1, block_tables)
-        out = out.reshape(b, hkv, g, cfg.hd)
-    else:
-        # XLA lane: gather each slot's logical cache view from the pool.
-        nbt = block_tables.shape[1]
-        k_ctx = k_pool[block_tables].reshape(b, nbt * block_size, hkv, cfg.hd)
-        v_ctx = v_pool[block_tables].reshape(b, nbt * block_size, hkv, cfg.hd)
-        scale = 1.0 / np.sqrt(cfg.hd)
-        qg = q.reshape(b, hkv, g, cfg.hd)
-        logits = jnp.einsum("bhgd,bkhd->bhgk", qg,
-                            k_ctx).astype(jnp.float32) * scale
-        pos = jnp.arange(nbt * block_size)[None, None, None, :]
-        logits = jnp.where(pos <= lengths[:, None, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(v_ctx.dtype)
-        out = jnp.einsum("bhgk,bkhd->bhgd", probs, v_ctx)
-    y = dense(p["wo"], out.reshape(b, 1, cfg.n_heads * cfg.hd))
+    with scope("attention"):
+        if backend.startswith("pallas"):
+            from repro.kernels import ops as K
+            out = K.attention_decode_paged(
+                q.reshape(b, cfg.n_heads, cfg.hd), k_pool, v_pool,
+                lengths + 1, block_tables)
+            out = out.reshape(b, hkv, g, cfg.hd)
+        else:
+            # XLA lane: gather each slot's logical cache view from the pool.
+            nbt = block_tables.shape[1]
+            k_ctx = k_pool[block_tables].reshape(b, nbt * block_size, hkv,
+                                                 cfg.hd)
+            v_ctx = v_pool[block_tables].reshape(b, nbt * block_size, hkv,
+                                                 cfg.hd)
+            scale = 1.0 / np.sqrt(cfg.hd)
+            qg = q.reshape(b, hkv, g, cfg.hd)
+            logits = jnp.einsum("bhgd,bkhd->bhgk", qg,
+                                k_ctx).astype(jnp.float32) * scale
+            pos = jnp.arange(nbt * block_size)[None, None, None, :]
+            logits = jnp.where(pos <= lengths[:, None, None, None], logits,
+                               -1e30)
+            probs = jax.nn.softmax(logits, axis=-1).astype(v_ctx.dtype)
+            out = jnp.einsum("bhgk,bkhd->bhgd", probs, v_ctx)
+    with scope("o_proj"):
+        y = dense(p["wo"], out.reshape(b, 1, cfg.n_heads * cfg.hd))
     return y, k_pool, v_pool

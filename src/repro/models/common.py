@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharding import constrain
+from repro.serve.trace import scope
 
 Params = Dict[str, Any]
 
@@ -32,12 +33,17 @@ def dense(p: Params, x: jnp.ndarray, role: Optional[str] = None,
     `kernels.dispatch.matmul_dispatch` context the chosen lane (XLA vs tuned
     Pallas) runs instead of the plain dot.  `activation` is fused into the
     tuned kernel's epilogue (applied after the dot on the XLA lane — same
-    numerics).  With no role/activation this is exactly `x @ w`."""
+    numerics).  A role also names the projection's scope in the compiled
+    program (`repro.serve.trace.scope`).  With no role/activation this is
+    exactly `x @ w`."""
     w = p["w"].astype(x.dtype)
     if role is None and activation is None:
         return x @ w
     from repro.kernels.dispatch import dispatch_dense
-    return dispatch_dense(role, x, w, activation=activation)
+    if role is None:
+        return dispatch_dense(role, x, w, activation=activation)
+    with scope(role):
+        return dispatch_dense(role, x, w, activation=activation)
 
 
 def norm_init(d: int) -> Params:
@@ -116,7 +122,9 @@ def unembed(p: Params, x: jnp.ndarray) -> jnp.ndarray:
 def lm_head_logits(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     """LM head projection `x @ w` (w: (d_model, vocab)), role-tagged so the
     serve plan's `lm_head` stage choice dispatches it (see kernels.dispatch)."""
-    return constrain(dense(p, x, role="lm_head"), ("batch", None, "vocab"))
+    with scope("lm_head"):
+        return constrain(dense(p, x, role="lm_head"),
+                         ("batch", None, "vocab"))
 
 
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,

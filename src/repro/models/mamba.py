@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
 from repro.models.common import Params, dense, dense_init, norm_init, rms_norm
+from repro.serve.trace import scope
 
 
 def _dims(cfg: ModelConfig):
@@ -225,31 +226,38 @@ def mamba_chunk_forward(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     n, hd = cfg.ssm_state, cfg.ssm_head_dim
     b, s, _ = x.shape
 
-    zxbcdt = dense(p["in_proj"], x, role="in_proj")
-    z, xbc_raw, dt = _split_proj(cfg, zxbcdt)
-    xbc = _causal_conv_carry(p["conv_w"], p["conv_b"], xbc_raw, conv_carry)
-    xs = xbc[..., :d_in].reshape(b, s, nh, hd)
-    Bmat = xbc[..., d_in : d_in + n]
-    Cmat = xbc[..., d_in + n :]
-    xs = constrain(xs, ("batch", None, "ssm_heads", None))
-
-    A = -jnp.exp(p["A_log"]).astype(jnp.float32)
+    with scope("in_proj"):
+        zxbcdt = dense(p["in_proj"], x, role="in_proj")
+        z, xbc_raw, dt = _split_proj(cfg, zxbcdt)
     seg_len = jnp.asarray(seg_len, jnp.int32)
-    dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-    row = jnp.arange(s)[None, :, None]
-    dtv = jnp.where(row < seg_len, dtv, 0.0)
-    y, h_final = ssd_chunked(xs.astype(jnp.float32), dtv, A,
-                             Bmat.astype(jnp.float32), Cmat.astype(jnp.float32),
-                             cfg.ssm_chunk, h0=h0.astype(jnp.float32))
-    y = y + xs.astype(jnp.float32) * p["D"][:, None]
-    y = y.reshape(b, s, d_in).astype(x.dtype)
-    y = rms_norm(p["norm"], y * jax.nn.silu(z))
-    out = dense(p["out_proj"], y, role="out_proj")
+    with scope("conv"):
+        xbc = _causal_conv_carry(p["conv_w"], p["conv_b"], xbc_raw,
+                                 conv_carry)
+        xs = xbc[..., :d_in].reshape(b, s, nh, hd)
+        Bmat = xbc[..., d_in : d_in + n]
+        Cmat = xbc[..., d_in + n :]
+        xs = constrain(xs, ("batch", None, "ssm_heads", None))
+        ext = jnp.concatenate([conv_carry.astype(xbc_raw.dtype), xbc_raw],
+                              axis=1)
+        new_carry = jax.lax.dynamic_slice(
+            ext, (jnp.int32(0), seg_len, jnp.int32(0)),
+            (b, cfg.conv_width - 1, conv_dim)).astype(jnp.float32)
 
-    ext = jnp.concatenate([conv_carry.astype(xbc_raw.dtype), xbc_raw], axis=1)
-    new_carry = jax.lax.dynamic_slice(
-        ext, (jnp.int32(0), seg_len, jnp.int32(0)),
-        (b, cfg.conv_width - 1, conv_dim)).astype(jnp.float32)
+    with scope("ssd"):
+        A = -jnp.exp(p["A_log"]).astype(jnp.float32)
+        dtv = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+        row = jnp.arange(s)[None, :, None]
+        dtv = jnp.where(row < seg_len, dtv, 0.0)
+        y, h_final = ssd_chunked(xs.astype(jnp.float32), dtv, A,
+                                 Bmat.astype(jnp.float32),
+                                 Cmat.astype(jnp.float32),
+                                 cfg.ssm_chunk, h0=h0.astype(jnp.float32))
+        y = y + xs.astype(jnp.float32) * p["D"][:, None]
+        y = y.reshape(b, s, d_in).astype(x.dtype)
+    # the gated norm feeds the out projection: one scope
+    with scope("out_proj"):
+        y = rms_norm(p["norm"], y * jax.nn.silu(z))
+        out = dense(p["out_proj"], y, role="out_proj")
     return out, new_carry, h_final
 
 
@@ -267,24 +275,30 @@ def mamba_decode(p: Params, cfg: ModelConfig, x: jnp.ndarray,
     n, hd = cfg.ssm_state, cfg.ssm_head_dim
     b = x.shape[0]
 
-    zxbcdt = dense(p["in_proj"], x, role="in_proj")
-    z, xbc_raw, dt = _split_proj(cfg, zxbcdt)
-    window = jnp.concatenate([conv_cache.astype(xbc_raw.dtype), xbc_raw], axis=1)
-    xbc = jax.nn.silu(
-        jnp.einsum("bwc,wc->bc", window, p["conv_w"].astype(window.dtype))
-        + p["conv_b"].astype(window.dtype))[:, None, :]
-    new_conv = window[:, 1:, :].astype(jnp.float32)
+    with scope("in_proj"):
+        zxbcdt = dense(p["in_proj"], x, role="in_proj")
+        z, xbc_raw, dt = _split_proj(cfg, zxbcdt)
+    with scope("conv"):
+        window = jnp.concatenate([conv_cache.astype(xbc_raw.dtype), xbc_raw],
+                                 axis=1)
+        xbc = jax.nn.silu(
+            jnp.einsum("bwc,wc->bc", window, p["conv_w"].astype(window.dtype))
+            + p["conv_b"].astype(window.dtype))[:, None, :]
+        new_conv = window[:, 1:, :].astype(jnp.float32)
 
-    xs = xbc[..., :d_in].reshape(b, nh, hd).astype(jnp.float32)
-    Bm = xbc[:, 0, d_in : d_in + n].astype(jnp.float32)      # (B, n)
-    Cm = xbc[:, 0, d_in + n :].astype(jnp.float32)
-    A = -jnp.exp(p["A_log"]).astype(jnp.float32)
-    dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"])  # (B, nh)
+    with scope("ssd"):
+        xs = xbc[..., :d_in].reshape(b, nh, hd).astype(jnp.float32)
+        Bm = xbc[:, 0, d_in : d_in + n].astype(jnp.float32)      # (B, n)
+        Cm = xbc[:, 0, d_in + n :].astype(jnp.float32)
+        A = -jnp.exp(p["A_log"]).astype(jnp.float32)
+        dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
+                              + p["dt_bias"])                    # (B, nh)
 
-    dA = jnp.exp(dtv * A)                                    # (B, nh)
-    h = h * dA[..., None, None] + jnp.einsum(
-        "bhp,bn,bh->bhpn", xs, Bm, dtv)
-    y = jnp.einsum("bhpn,bn->bhp", h, Cm) + xs * p["D"][:, None]
-    y = y.reshape(b, 1, d_in).astype(x.dtype)
-    y = rms_norm(p["norm"], y * jax.nn.silu(z))
-    return dense(p["out_proj"], y, role="out_proj"), new_conv, h
+        dA = jnp.exp(dtv * A)                                    # (B, nh)
+        h = h * dA[..., None, None] + jnp.einsum(
+            "bhp,bn,bh->bhpn", xs, Bm, dtv)
+        y = jnp.einsum("bhpn,bn->bhp", h, Cm) + xs * p["D"][:, None]
+        y = y.reshape(b, 1, d_in).astype(x.dtype)
+    with scope("out_proj"):
+        y = rms_norm(p["norm"], y * jax.nn.silu(z))
+        return dense(p["out_proj"], y, role="out_proj"), new_conv, h

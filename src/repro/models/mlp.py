@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain
 from repro.models.common import Params, dense, dense_init
+from repro.serve.trace import scope
 
 
 def _act(x, kind: str):
@@ -44,12 +45,15 @@ def mlp_logical_axes(gated: bool = True) -> Params:
 def mlp_apply(p: Params, x: jnp.ndarray, act: str = "silu") -> jnp.ndarray:
     # role-tagged for the serve plan's stage matmul dispatch; the activation
     # rides into the tuned kernel's epilogue (XLA lane applies it after).
-    if "w_gate" in p:
-        h = dense(p["w_in"], x, role="mlp_up")
-        h = dense(p["w_gate"], x, role="mlp_up", activation=act) * h
-    else:
-        h = dense(p["w_in"], x, role="mlp_up", activation=act)
-    h = constrain(h, ("batch", None, "ffn"))
+    # The gate product sits in the up projection's scope too, so a fusion
+    # rooted at it is charged to mlp_up.
+    with scope("mlp_up"):
+        if "w_gate" in p:
+            h = dense(p["w_in"], x, role="mlp_up")
+            h = dense(p["w_gate"], x, role="mlp_up", activation=act) * h
+        else:
+            h = dense(p["w_in"], x, role="mlp_up", activation=act)
+        h = constrain(h, ("batch", None, "ffn"))
     return dense(p["w_out"], h, role="mlp_down")
 
 

@@ -42,6 +42,7 @@ from repro.models.common import (
     dense,
     dense_init,
 )
+from repro.serve.trace import scope
 
 Batch = Dict[str, jnp.ndarray]
 
@@ -61,6 +62,24 @@ def _remat(cfg: ModelConfig, fn):
         return jax.checkpoint(
             fn, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
     return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
+
+
+def _scoped_layer_out(cfg: ModelConfig, bp: Params, x, y):
+    """The serving layers' tail: x + attention output `y`, then the MLP
+    block with its residual.  Every op carries a scope of its own (a
+    residual add sits in the scope of the projection feeding it, so a
+    fusion rooted at the add is charged to that projection), leaving
+    exactly ".../layers" to what the layer scan itself emits."""
+    with scope("o_proj"):
+        x = x + y
+    with scope("norm"):
+        h = _norm(cfg, bp["mlp_norm"], x)
+    if cfg.family == "moe":
+        y = F.moe_apply(bp["moe"], cfg, h, cfg.act)
+    else:
+        y = F.mlp_apply(bp["mlp"], h, cfg.act)
+    with scope("mlp_down"):
+        return x + y
 
 
 # ===================================================================== blocks
@@ -284,24 +303,23 @@ class DecoderLM:
         admitting a request into the in-flight batch is a pure data update —
         the jitted program is reused as-is."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
+        with scope("embed"):
+            x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
 
         def body(x, layer):
             bp, kp, vp = layer
-            h = _norm(cfg, bp["attn_norm"], x)
+            with scope("norm"):
+                h = _norm(cfg, bp["attn_norm"], x)
             y, kp, vp = A.attn_decode_paged(
                 bp["attn"], cfg, h, kp, vp, block_tables, lengths,
                 backend=attn_backend)
-            x = x + y
-            h = _norm(cfg, bp["mlp_norm"], x)
-            if cfg.family == "moe":
-                x = x + F.moe_apply(bp["moe"], cfg, h, cfg.act)
-            else:
-                x = x + F.mlp_apply(bp["mlp"], h, cfg.act)
-            return x, (kp, vp)
+            return _scoped_layer_out(cfg, bp, x, y), (kp, vp)
 
-        x, (ks, vs) = runmode.layer_scan(body, x, (params["blocks"], k_pool, v_pool))
-        x = _norm(cfg, params["final_norm"], x)
+        with scope("layers"):
+            x, (ks, vs) = runmode.layer_scan(
+                body, x, (params["blocks"], k_pool, v_pool))
+        with scope("final_norm"):
+            x = _norm(cfg, params["final_norm"], x)
         logits = lm_head_logits(params["lm_head"], x)
         return logits, ks, vs
 
@@ -329,33 +347,32 @@ class DecoderLM:
         first sampled token of every segment that completes its prompt
         this step — ks, vs)."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
-        b, c, _ = x.shape
-        _, pos, _ = A.packed_row_map(seg_info, c)   # pos zeroed on padding
-        positions = self._position_ids(b, pos)
+        with scope("embed"):
+            x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
+            b, c, _ = x.shape
+            _, pos, _ = A.packed_row_map(seg_info, c)  # pos 0 on padding
+            positions = self._position_ids(b, pos)
 
         def body(x, layer):
             bp, kp, vp = layer
-            h = _norm(cfg, bp["attn_norm"], x)
+            with scope("norm"):
+                h = _norm(cfg, bp["attn_norm"], x)
             y, kp, vp = A.attn_prefill_packed(
                 bp["attn"], cfg, h, kp, vp, seg_tables, positions,
                 seg_info, backend=attn_backend,
                 backend_config=attn_config)
-            x = x + y
-            h = _norm(cfg, bp["mlp_norm"], x)
-            if cfg.family == "moe":
-                x = x + F.moe_apply(bp["moe"], cfg, h, cfg.act)
-            else:
-                x = x + F.mlp_apply(bp["mlp"], h, cfg.act)
-            return x, (kp, vp)
+            return _scoped_layer_out(cfg, bp, x, y), (kp, vp)
 
-        x, (ks, vs) = runmode.layer_scan(body, x,
-                                         (params["blocks"], k_pool, v_pool))
-        x = _norm(cfg, params["final_norm"], x)
-        info = jnp.asarray(seg_info, jnp.int32)
-        last = jnp.clip(info[:, 0] + info[:, 1] - 1, 0, c - 1)   # (S,)
-        x_last = x[:, last]                                      # (1, S, d)
-        logits = lm_head_logits(params["lm_head"], x_last)
+        with scope("layers"):
+            x, (ks, vs) = runmode.layer_scan(
+                body, x, (params["blocks"], k_pool, v_pool))
+        with scope("final_norm"):
+            x = _norm(cfg, params["final_norm"], x)
+        with scope("lm_head"):
+            info = jnp.asarray(seg_info, jnp.int32)
+            last = jnp.clip(info[:, 0] + info[:, 1] - 1, 0, c - 1)  # (S,)
+            x_last = x[:, last]                                    # (1, S, d)
+            logits = lm_head_logits(params["lm_head"], x_last)
         return logits, ks, vs
 
     @staticmethod
@@ -499,31 +516,37 @@ class MambaLM:
         Returns (logits (1, 1, V) at the segment's last real row, conv_pool,
         ssm_pool)."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
+        with scope("embed"):
+            x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
         state_idx = jnp.asarray(state_idx, jnp.int32)
         seg_len = jnp.asarray(seg_len, jnp.int32)
         fresh = jnp.asarray(seg_start, jnp.int32) == 0
 
-        conv_c = conv_pool[:, state_idx]                 # (L, W-1, conv_dim)
-        ssm_c = ssm_pool[:, state_idx]                   # (L, nh, hd, n)
-        conv_c = jnp.where(fresh, jnp.zeros_like(conv_c), conv_c)
-        ssm_c = jnp.where(fresh, jnp.zeros_like(ssm_c), ssm_c)
-
         def body(x, layer):
             bp, cc, hc = layer
-            hin = _norm(cfg, bp["norm"], x)
+            with scope("norm"):
+                hin = _norm(cfg, bp["norm"], x)
             y, cc, hc = M.mamba_chunk_forward(bp["mamba"], cfg, hin,
                                               cc[None], hc[None], seg_len)
-            return x + y, (cc[0], hc[0])
+            with scope("out_proj"):
+                return x + y, (cc[0], hc[0])
 
-        x, (convs, hs) = runmode.layer_scan(
-            body, x, (params["blocks"], conv_c, ssm_c))
-        conv_pool = conv_pool.at[:, state_idx].set(convs)
-        ssm_pool = ssm_pool.at[:, state_idx].set(hs)
-        x = _norm(cfg, params["final_norm"], x)
-        last = jnp.clip(seg_len - 1, 0, x.shape[1] - 1)
-        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        logits = lm_head_logits(params["lm_head"], x_last)
+        # the state pool's row read and write-back belong to the scan
+        with scope("layers"):
+            conv_c = conv_pool[:, state_idx]             # (L, W-1, conv_dim)
+            ssm_c = ssm_pool[:, state_idx]               # (L, nh, hd, n)
+            conv_c = jnp.where(fresh, jnp.zeros_like(conv_c), conv_c)
+            ssm_c = jnp.where(fresh, jnp.zeros_like(ssm_c), ssm_c)
+            x, (convs, hs) = runmode.layer_scan(
+                body, x, (params["blocks"], conv_c, ssm_c))
+            conv_pool = conv_pool.at[:, state_idx].set(convs)
+            ssm_pool = ssm_pool.at[:, state_idx].set(hs)
+        with scope("final_norm"):
+            x = _norm(cfg, params["final_norm"], x)
+        with scope("lm_head"):
+            last = jnp.clip(seg_len - 1, 0, x.shape[1] - 1)
+            x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+            logits = lm_head_logits(params["lm_head"], x_last)
         return logits, conv_pool, ssm_pool
 
     def decode_step_slots(self, params: Params, conv_pool: jnp.ndarray,
@@ -535,22 +558,28 @@ class MambaLM:
         garbage by construction and never reach a real request's row.
         tokens: (B, 1).  Returns (logits (B, 1, V), conv_pool, ssm_pool)."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
+        with scope("embed"):
+            x = embed(params["embed"], tokens, jnp.dtype(cfg.dtype))
         state_idx = jnp.asarray(state_idx, jnp.int32)
-        conv = conv_pool[:, state_idx]                   # (L, B, W-1, conv)
-        ssm = ssm_pool[:, state_idx]                     # (L, B, nh, hd, n)
 
         def body(x, layer):
             bp, cv, h = layer
-            hin = _norm(cfg, bp["norm"], x)
+            with scope("norm"):
+                hin = _norm(cfg, bp["norm"], x)
             y, cv, h = M.mamba_decode(bp["mamba"], cfg, hin, cv, h)
-            return x + y, (cv, h)
+            with scope("out_proj"):
+                return x + y, (cv, h)
 
-        x, (convs, hs) = runmode.layer_scan(
-            body, x, (params["blocks"], conv, ssm))
-        conv_pool = conv_pool.at[:, state_idx].set(convs)
-        ssm_pool = ssm_pool.at[:, state_idx].set(hs)
-        x = _norm(cfg, params["final_norm"], x)
+        # the state pool's row gather and write-back belong to the scan
+        with scope("layers"):
+            conv = conv_pool[:, state_idx]               # (L, B, W-1, conv)
+            ssm = ssm_pool[:, state_idx]                 # (L, B, nh, hd, n)
+            x, (convs, hs) = runmode.layer_scan(
+                body, x, (params["blocks"], conv, ssm))
+            conv_pool = conv_pool.at[:, state_idx].set(convs)
+            ssm_pool = ssm_pool.at[:, state_idx].set(hs)
+        with scope("final_norm"):
+            x = _norm(cfg, params["final_norm"], x)
         logits = lm_head_logits(params["lm_head"], x)
         return logits, conv_pool, ssm_pool
 

@@ -106,10 +106,16 @@ def configure_from_argv(argv: Optional[List[str]] = None) -> Optional[int]:
 def configure_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its directory; returns it.
 
-    With `JAX_COMPILATION_CACHE_DIR` set nothing is changed: JAX already
-    keeps its cache there.  Otherwise the cache goes to `CHECKOUT_CACHE`."""
+    With `JAX_COMPILATION_CACHE_DIR` set the directory is left alone: JAX
+    already keeps its cache there.  Otherwise the cache goes to
+    `CHECKOUT_CACHE`.
+    Either way an entry's key covers the program's metadata: the serving
+    runtime reads each step program's scope table from the executable
+    (`repro.serve.trace.launch`), so a program must never load the
+    executable of one that differs from it only in metadata."""
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get(CACHE_ENV)
     if not path:
         path = str(CHECKOUT_CACHE)

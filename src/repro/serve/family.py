@@ -44,15 +44,16 @@ The adapter protocol (duck-typed; both classes implement it):
     resume_commit(group) -> [nbytes, ...]     host buffers -> device state:
                         ONE commit invocation for up to resume_segments
                         re-admitted requests
-    dispatch(params, dec_rids, lengths, last_tok, chunks,
-             dec_sampling, dec_keys)
-                        -> (next_tokens (slots,), seg_next | None)
+    dispatch(params, slots, lengths, last_tok, chunks)
+                        -> (next_tokens (slots,), seg_next | None, compiled)
 
-The engine hands `dispatch` the decode lane's per-slot sampling/key arrays
-(built by `repro.serve.sampling.slot_sampling_arrays`); each adapter packs
-the chunk lane's per-segment arrays itself (token index 0 — a segment's
-sample is the request's FIRST token) and threads both into its step
-programs as traced data.
+`dispatch` builds the decode lane's per-slot arrays from the scheduler's
+slots (`decode_lane_host`: rids, sampling knobs and key triples) and the
+chunk lane's per-segment arrays (token index 0 — a segment's sample is
+the request's FIRST token), and threads both into its step programs as
+traced data.  Its phases are the `serve.inputs` / `serve.launch` /
+`serve.sync` spans (`repro.serve.trace`); `compiled` says the call built a
+new executable, whose scope table `trace.launch` recorded.
 
 The engine reads `_unified` / `_decode_only` / `_commit` off the adapter
 for compile-count accounting (each is a jitted program whose
@@ -80,9 +81,21 @@ from repro.launch.steps import (
 )
 from repro.serve.kvcache import NULL_BLOCK, PagedKVCache
 from repro.serve.router import PlanRouter, serve_stages
-from repro.serve.sampling import segment_sampling_arrays
+from repro.serve.sampling import (segment_sampling_arrays,
+                                  slot_sampling_arrays)
 from repro.serve.scheduler import PagedCapacity, ServeRequest
 from repro.serve.statecache import SlotStateCache, SlotCapacity
+from repro.serve.trace import launch, span
+
+
+def decode_lane_host(slots):
+    """The decode lane's per-slot host arrays: each slot's rid where it
+    decodes (None where empty or still prefilling — masked exactly like an
+    empty slot), and its sampling knobs and PRNG key triple."""
+    dec_rids = [r.rid if (r is not None and not r.prefilling) else None
+                for r in slots]
+    dec_sampling, dec_keys = slot_sampling_arrays(slots)
+    return dec_rids, dec_sampling, dec_keys
 
 
 def resolve_family_adapter(model):
@@ -280,35 +293,41 @@ class DecoderFamilyAdapter:
         info[len(chunks):, 0] = q0            # idle slots: empty span at fill
         return toks, tables, info
 
-    def dispatch(self, params, dec_rids: List[Optional[int]],
+    def dispatch(self, params, slots: List[Optional[ServeRequest]],
                  lengths: np.ndarray, last_tok: np.ndarray,
-                 chunks: List[Tuple[ServeRequest, int, int]],
-                 dec_sampling: np.ndarray, dec_keys: np.ndarray):
+                 chunks: List[Tuple[ServeRequest, int, int]]):
         """Run ONE step program invocation: the unified step when `chunks`
         carries prompt work, else the decode-only fast path.  Returns the
-        decode lane's next tokens (host, (slots,)) and the chunk segments'
-        next-token samples ((segments,) or None)."""
-        bt = jnp.asarray(self.cache.table_array(dec_rids))
-        lens = jnp.asarray(lengths)
-        tokens = jnp.asarray(last_tok[:, None])
-        dsp = jnp.asarray(dec_sampling)
-        dks = jnp.asarray(dec_keys)
+        decode lane's next tokens (host, (slots,)), the chunk segments'
+        next-token samples ((segments,) or None) and whether the call
+        compiled."""
+        with span("serve.inputs"):
+            dec_rids, dec_sampling, dec_keys = decode_lane_host(slots)
+            dec = (jnp.asarray(self.cache.table_array(dec_rids)),
+                   jnp.asarray(lengths), jnp.asarray(last_tok[:, None]))
+            samp = (jnp.asarray(dec_sampling), jnp.asarray(dec_keys))
+            if chunks:
+                ch_toks, seg_tables, seg_info = self._chunk_inputs(chunks)
+                seg_sp, seg_ks = segment_sampling_arrays(chunks,
+                                                         self.chunk_segments)
+                chunk = tuple(map(jnp.asarray, (ch_toks, seg_tables,
+                                                seg_info)))
+                seg_samp = (jnp.asarray(seg_sp), jnp.asarray(seg_ks))
         if chunks:
-            ch_toks, seg_tables, seg_info = self._chunk_inputs(chunks)
-            seg_sp, seg_ks = segment_sampling_arrays(chunks,
-                                                     self.chunk_segments)
-            nxt_dev, seg_next_dev, self.cache.k, self.cache.v = self._unified(
-                params, self.cache.k, self.cache.v, bt, lens, tokens,
-                jnp.asarray(ch_toks), jnp.asarray(seg_tables),
-                jnp.asarray(seg_info), dsp, dks,
-                jnp.asarray(seg_sp), jnp.asarray(seg_ks))
+            (nxt_dev, seg_next_dev, self.cache.k, self.cache.v), compiled = \
+                launch(self._unified, params, self.cache.k, self.cache.v,
+                       *dec, *chunk, *samp, *seg_samp)
+        else:
+            # decode-only fast path: no prompt work pending, so the step
+            # skips the chunk-wide forward instead of masking it
+            (nxt_dev, self.cache.k, self.cache.v), compiled = launch(
+                self._decode_only, params, self.cache.k, self.cache.v,
+                *dec, *samp)
+        with span("serve.sync"):
             nxt = np.asarray(nxt_dev, np.int32)
-            return nxt, np.asarray(seg_next_dev, np.int32)
-        # decode-only fast path: no prompt work pending, so the step
-        # skips the chunk-wide forward instead of masking it
-        nxt_dev, self.cache.k, self.cache.v = self._decode_only(
-            params, self.cache.k, self.cache.v, bt, lens, tokens, dsp, dks)
-        return np.asarray(nxt_dev, np.int32), None
+            seg_next = (np.asarray(seg_next_dev, np.int32) if chunks
+                        else None)
+        return nxt, seg_next, compiled
 
     def occupancy(self) -> float:
         return self.cache.alloc.occupancy()
@@ -425,37 +444,42 @@ class SSMFamilyAdapter:
         return nbytes
 
     # ------------------------------------------------------------- dispatch
-    def dispatch(self, params, dec_rids: List[Optional[int]],
+    def dispatch(self, params, slots: List[Optional[ServeRequest]],
                  lengths: np.ndarray, last_tok: np.ndarray,
-                 chunks: List[Tuple[ServeRequest, int, int]],
-                 dec_sampling: np.ndarray, dec_keys: np.ndarray):
+                 chunks: List[Tuple[ServeRequest, int, int]]):
         """One ssm step program invocation.  The decode lane maps each slot
         to its state row (`index_array`; idle/prefilling slots hit the null
         row); the chunk lane carries at most ONE segment (packing width 1).
         Traced scalars go in as strongly-typed np.int32 so the weak-typed
         Python-int path can never trace a second executable."""
-        state_idx = jnp.asarray(self.cache.index_array(dec_rids))
-        tokens = jnp.asarray(last_tok[:, None])
-        dsp = jnp.asarray(dec_sampling)
-        dks = jnp.asarray(dec_keys)
+        with span("serve.inputs"):
+            dec_rids, dec_sampling, dec_keys = decode_lane_host(slots)
+            dec = (jnp.asarray(self.cache.index_array(dec_rids)),
+                   jnp.asarray(last_tok[:, None]))
+            samp = (jnp.asarray(dec_sampling), jnp.asarray(dec_keys))
+            if chunks:
+                req, start, n = chunks[0]
+                ch_toks = np.zeros((1, self.chunk_width), np.int32)
+                ch_toks[0, :n] = req.prompt[start:start + n]
+                row = self.cache.alloc.slot_of(req.rid)
+                ch_sp, ch_ks = segment_sampling_arrays(chunks, 1)
+                chunk = (jnp.asarray(ch_toks), np.int32(row), np.int32(n),
+                         np.int32(start))
+                ch_samp = (jnp.asarray(ch_sp), jnp.asarray(ch_ks))
         if chunks:
-            req, start, n = chunks[0]
-            ch_toks = np.zeros((1, self.chunk_width), np.int32)
-            ch_toks[0, :n] = req.prompt[start:start + n]
-            row = self.cache.alloc.slot_of(req.rid)
-            ch_sp, ch_ks = segment_sampling_arrays(chunks, 1)
-            nxt_dev, ch_next_dev, self.cache.conv, self.cache.ssm = \
-                self._unified(
-                    params, self.cache.conv, self.cache.ssm, state_idx,
-                    tokens, jnp.asarray(ch_toks), np.int32(row),
-                    np.int32(n), np.int32(start), dsp, dks,
-                    jnp.asarray(ch_sp), jnp.asarray(ch_ks))
+            (nxt_dev, ch_next_dev, self.cache.conv, self.cache.ssm), \
+                compiled = launch(self._unified, params, self.cache.conv,
+                                  self.cache.ssm, *dec, *chunk, *samp,
+                                  *ch_samp)
+        else:
+            (nxt_dev, self.cache.conv, self.cache.ssm), compiled = launch(
+                self._decode_only, params, self.cache.conv, self.cache.ssm,
+                *dec, *samp)
+        with span("serve.sync"):
             nxt = np.asarray(nxt_dev, np.int32)
-            return nxt, np.asarray(ch_next_dev, np.int32).reshape(1)
-        nxt_dev, self.cache.conv, self.cache.ssm = self._decode_only(
-            params, self.cache.conv, self.cache.ssm, state_idx, tokens,
-            dsp, dks)
-        return np.asarray(nxt_dev, np.int32), None
+            ch_next = (np.asarray(ch_next_dev, np.int32).reshape(1)
+                       if chunks else None)
+        return nxt, ch_next, compiled
 
     def occupancy(self) -> float:
         return self.cache.alloc.occupancy()

@@ -60,8 +60,10 @@ class ServeMetrics:
     chunk_lane_tokens: int = 0
     packed_segments: int = 0
     decode_only_steps: int = 0
-    # device-compute time (always wall-clock, even under a virtual engine
-    # clock) — comparable with FixedBatchEngine's prefill_s/decode_s split.
+    # host wall time of each step's dispatch: input uploads, the program
+    # and the read-back of its tokens (always wall-clock, even under a
+    # virtual engine clock; device time is in a profiler trace) —
+    # comparable with FixedBatchEngine's prefill_s/decode_s split.
     # The unified program carries both lanes in one invocation, so a mixed
     # step's time goes to decode_time_s; prefill_time_s collects the steps
     # that carried ONLY chunk work (no decode rows), and decode-only fast-

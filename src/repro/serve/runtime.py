@@ -90,7 +90,7 @@ arrival traces in wall time or virtual time with identical scheduling.
 Passing a `repro.serve.trace.TraceRecorder` as `trace=` records every
 scheduler / allocator / step decision as a typed event on the engine clock
 (admission, chunk packing, preemption and swap, pool accounting, step
-dispatch with lane fill and device time, program compiles), each stamped
+dispatch with lane fill and host time, program compiles), each stamped
 with the serving family.  The recorder threads through the scheduler and
 the family's allocator, exports to Chrome-trace-event JSON for
 `ui.perfetto.dev`, and feeds the trace audit (`repro.serve.traceview`).
@@ -114,11 +114,10 @@ from repro.serve.family import resolve_family_adapter
 from repro.serve.kvcache import KVCacheConfig
 from repro.serve.metrics import ServeMetrics
 from repro.serve.router import DEFAULT_CHUNK_TOKENS, PlanRouter
-from repro.serve.sampling import (SamplingParams, slot_sampling_arrays,
-                                  truncate_at_eos)
+from repro.serve.sampling import SamplingParams, truncate_at_eos
 from repro.serve.scheduler import ContinuousScheduler, ServeRequest
 from repro.serve.statecache import StateCacheConfig
-from repro.serve.trace import NULL_RECORDER, TraceRecorder
+from repro.serve.trace import NULL_RECORDER, TraceRecorder, span
 
 
 @dataclasses.dataclass
@@ -372,7 +371,7 @@ class ContinuousEngine:
         swap_in_s = batch_s / len(group)
         self.metrics.record_resume_commit(len(group))
         if self.trace.enabled and self._commit._cache_size() > n_commit:
-            self.trace.emit("compile", program="commit", device_s=batch_s)
+            self.trace.emit("compile", program="commit", host_s=batch_s)
         for req, nb in zip(group, nbytes):
             if self.trace.enabled:
                 self.trace.emit("swap_in", rid=req.rid, nbytes=nb)
@@ -432,56 +431,68 @@ class ContinuousEngine:
         chunk lane + decode lane) or — when no prompt work is pending —
         the decode-only fast path, which skips the idle chunk lane
         entirely.  Returns False when nothing ran."""
+        with span("serve.step") as step_span:
+            return self._step(step_span)
+
+    def _step(self, step_span) -> bool:
+        """The body of `step`, inside its `serve.step` span; each phase
+        opens a child span (see `repro.serve.trace.SPAN_NAMES`)."""
         now = self.now_fn()
-        admitted = self.scheduler.admit(now)
-        resuming = [r for r in admitted if self.adapter.is_swapped(r.rid)]
-        if self.cfg.prefix_sharing:
-            # fresh admissions run nothing; a non-zero `prefilled` on one
-            # means admission adopted that many prompt tokens' KV from the
-            # prefix index — work the chunk lane will never do
-            rs = {r.rid for r in resuming}
-            for req in admitted:
-                if req.rid not in rs and req.prefilled > 0:
-                    self.metrics.record_prefix_hit(req.prefilled)
-        if resuming:
-            self._resume_all(resuming)
-        # fresh admissions run nothing here: their prompts stream
-        # through the unified step's chunk lane, starting this step
+        with span("serve.admit") as admit_span:
+            admitted = self.scheduler.admit(now)
+            resuming = [r for r in admitted if self.adapter.is_swapped(r.rid)]
+            if self.cfg.prefix_sharing:
+                # fresh admissions run nothing; a non-zero `prefilled` on
+                # one means admission adopted that many prompt tokens' KV
+                # from the prefix index — work the chunk lane will never do
+                rs = {r.rid for r in resuming}
+                for req in admitted:
+                    if req.rid not in rs and req.prefilled > 0:
+                        self.metrics.record_prefix_hit(req.prefilled)
+            if resuming:
+                self._resume_all(resuming)
+            # fresh admissions run nothing here: their prompts stream
+            # through the unified step's chunk lane, starting this step
+            admit_span.set_metadata(admitted=len(admitted),
+                                    resumed=len(resuming))
 
-        chunks = self.scheduler.next_chunks(self._chunk_width,
-                                            self._chunk_segments)
+        preempted = self.metrics.preemptions
+        with span("serve.schedule"):
+            chunks = self.scheduler.next_chunks(self._chunk_width,
+                                                self._chunk_segments)
+            # on-demand growth for the decode batch: every decoding request
+            # secures the unit its next write lands in.  A request
+            # preempted as some later grower's victim drops out of this
+            # step (slot is None by then) — including, possibly, any of the
+            # packed segments' requests.
+            for req in [r for r in self.scheduler.slots
+                        if r is not None and not r.prefilling]:
+                if req.slot is not None:
+                    self._ensure_blocks(req)
+            # chunk-claim: each packed segment's request must hold its
+            # family footprint before dispatch (ssm: lazy state-row claim;
+            # paged: copy-on-write any shared block under the chunk's rows
+            # — the prompt's blocks themselves were allocated at admission)
+            chunks = [ch for ch in chunks
+                      if ch[0].slot is not None and self._claim_chunk(*ch)]
+            chunks = [ch for ch in chunks if ch[0].slot is not None]
+            decoding = [r for r in self.scheduler.slots
+                        if r is not None and not r.prefilling]
+        preempted = self.metrics.preemptions - preempted
 
-        # on-demand growth for the decode batch: every decoding request
-        # secures the unit its next write lands in.  A request preempted
-        # as some later grower's victim drops out of this step (slot is
-        # None by then) — including, possibly, any of the packed segments'
-        # requests.
-        for req in [r for r in self.scheduler.slots
-                    if r is not None and not r.prefilling]:
-            if req.slot is not None:
-                self._ensure_blocks(req)
-        # chunk-claim: each packed segment's request must hold its family
-        # footprint before dispatch (ssm: lazy state-row claim; paged:
-        # copy-on-write any shared block under the chunk's rows — the
-        # prompt's blocks themselves were allocated at admission)
-        chunks = [ch for ch in chunks
-                  if ch[0].slot is not None and self._claim_chunk(*ch)]
-        chunks = [ch for ch in chunks if ch[0].slot is not None]
-
-        decoding = [r for r in self.scheduler.slots
-                    if r is not None and not r.prefilling]
         if not decoding and not chunks:
+            step_span.set_metadata(kind="none", preempted=preempted)
             return bool(admitted)
-
-        # decode lane inputs: prefilling slots are masked exactly like empty
-        # ones (null index, zero length) — their dummy row writes to the sink
-        dec_rids = [r.rid if (r is not None and not r.prefilling) else None
-                    for r in self.scheduler.slots]
 
         trace = self.trace
         kind = "unified" if chunks else "decode_only"
         step_idx = self._step_idx
         self._step_idx += 1
+        chunk_tokens = sum(n for _, _, n in chunks)
+        step_span.set_metadata(step=step_idx, kind=kind,
+                               decode_rows=len(decoding),
+                               chunk_tokens=chunk_tokens,
+                               segments=len(chunks), preempted=preempted)
         if trace.enabled:
             for req, start, n in chunks:
                 trace.emit("chunk_scheduled", t=now, rid=req.rid,
@@ -489,84 +500,83 @@ class ContinuousEngine:
             trace.emit("step_begin", t=now, step=step_idx, kind=kind,
                        family=self.family, mesh=self.mesh_tag,
                        lane_width=self._chunk_width if chunks else 0,
-                       segments=len(chunks),
-                       chunk_tokens=sum(n for _, _, n in chunks),
+                       segments=len(chunks), chunk_tokens=chunk_tokens,
                        decode_rows=len(decoding))
-            prog = self._unified if chunks else self._decode_only
-            n_compiled = prog._cache_size()
-
-        # per-slot sampling knobs + PRNG key triples for the decode lane,
-        # rebuilt each step from slot residency (pure data — the arrays are
-        # traced inputs, so per-request sampling never retraces a program)
-        dec_sampling, dec_keys = slot_sampling_arrays(self.scheduler.slots)
 
         t0 = time.perf_counter()
-        nxt, seg_next = self.adapter.dispatch(
-            self.params, dec_rids, self._lengths, self._last_tok, chunks,
-            dec_sampling, dec_keys)
+        nxt, seg_next, compiled = self.adapter.dispatch(
+            self.params, self.scheduler.slots, self._lengths,
+            self._last_tok, chunks)
         step_s = time.perf_counter() - t0
-        if trace.enabled and prog._cache_size() > n_compiled:
-            trace.emit("compile", program=kind, device_s=step_s)
-        # attribute chunk-only steps to prefill time, everything else to
-        # decode time
-        if decoding:
-            self.metrics.decode_time_s += step_s
-        else:
-            self.metrics.prefill_time_s += step_s
 
-        now = self.now_fn()
-        if trace.enabled:
-            trace.emit("step_end", t=now, step=step_idx, kind=kind,
-                       family=self.family, mesh=self.mesh_tag,
-                       lane_width=self._chunk_width if chunks else 0,
-                       segments=len(chunks),
-                       chunk_tokens=sum(n for _, _, n in chunks),
-                       decode_rows=len(decoding), device_s=step_s)
-        if chunks:
-            self.metrics.record_chunk_step([n for _, _, n in chunks],
-                                           self._chunk_width)
-            for i, (req, start, n) in enumerate(chunks):
-                req.prefilled = start + n
-                # the chunk's KV is committed and final: index the prompt's
-                # covered full-block prefixes for later admissions to adopt
-                self.adapter.register_prefix(req)
-                if trace.enabled:
-                    trace.emit("chunk_committed", t=now, rid=req.rid,
-                               start=start, n=n, prefilled=req.prefilled)
-                if not req.prefilling:        # this chunk finished the prompt
-                    first = int(seg_next[i])
-                    req.output.append(first)
-                    req.first_token_time = now
-                    trace.emit("first_token", t=now, rid=req.rid, token=first)
-                    self.metrics.record_first_token(now - req.arrival_time)
-                    self.metrics.prefills += 1
+        with span("serve.commit") as commit_span:
+            n_done = len(self._done)
+            if trace.enabled and compiled:
+                trace.emit("compile", program=kind, host_s=step_s)
+            # attribute chunk-only steps to prefill time, everything else
+            # to decode time
+            if decoding:
+                self.metrics.decode_time_s += step_s
+            else:
+                self.metrics.prefill_time_s += step_s
+
+            now = self.now_fn()
+            if trace.enabled:
+                trace.emit("step_end", t=now, step=step_idx, kind=kind,
+                           family=self.family, mesh=self.mesh_tag,
+                           lane_width=self._chunk_width if chunks else 0,
+                           segments=len(chunks), chunk_tokens=chunk_tokens,
+                           decode_rows=len(decoding), host_s=step_s)
+            if chunks:
+                self.metrics.record_chunk_step([n for _, _, n in chunks],
+                                               self._chunk_width)
+                for i, (req, start, n) in enumerate(chunks):
+                    req.prefilled = start + n
+                    # the chunk's KV is committed and final: index the
+                    # prompt's covered full-block prefixes for later
+                    # admissions to adopt
+                    self.adapter.register_prefix(req)
+                    if trace.enabled:
+                        trace.emit("chunk_committed", t=now, rid=req.rid,
+                                   start=start, n=n, prefilled=req.prefilled)
+                    if not req.prefilling:    # this chunk ended the prompt
+                        first = int(seg_next[i])
+                        req.output.append(first)
+                        req.first_token_time = now
+                        trace.emit("first_token", t=now, rid=req.rid,
+                                   token=first)
+                        self.metrics.record_first_token(
+                            now - req.arrival_time)
+                        self.metrics.prefills += 1
+                        slot = req.slot
+                        self._lengths[slot] = req.prompt_len
+                        self._last_tok[slot] = first
+                        if self._finished(req):
+                            self._retire(req, now)
+            elif decoding:
+                self.metrics.record_decode_only_step()
+
+            if decoding:
+                self.metrics.record_step(len(decoding), self.cfg.max_slots,
+                                         self.adapter.occupancy())
+                emit_tokens = trace.enabled
+                for req in decoding:
                     slot = req.slot
-                    self._lengths[slot] = req.prompt_len
-                    self._last_tok[slot] = first
+                    req.output.append(int(nxt[slot]))
+                    self._lengths[slot] += 1
+                    self._last_tok[slot] = nxt[slot]
+                    if emit_tokens:
+                        trace.emit("decode_token", t=now, rid=req.rid,
+                                   token=int(nxt[slot]))
                     if self._finished(req):
                         self._retire(req, now)
-        elif decoding:
-            self.metrics.record_decode_only_step()
-
-        if decoding:
-            self.metrics.record_step(len(decoding), self.cfg.max_slots,
-                                     self.adapter.occupancy())
-            emit_tokens = trace.enabled
-            for req in decoding:
-                slot = req.slot
-                req.output.append(int(nxt[slot]))
-                self._lengths[slot] += 1
-                self._last_tok[slot] = nxt[slot]
-                if emit_tokens:
-                    trace.emit("decode_token", t=now, rid=req.rid,
-                               token=int(nxt[slot]))
-                if self._finished(req):
-                    self._retire(req, now)
-        # copy-on-write copies performed while growing/claiming this step
-        # (the allocator counts them; state-row allocators have none)
-        drain = getattr(self.adapter.alloc, "drain_cow_copies", None)
-        if drain is not None:
-            copied = drain()
-            if copied:
-                self.metrics.record_cow(copied)
+            # copy-on-write copies performed while growing/claiming this
+            # step (the allocator counts them; state-row allocators have
+            # none)
+            drain = getattr(self.adapter.alloc, "drain_cow_copies", None)
+            if drain is not None:
+                copied = drain()
+                if copied:
+                    self.metrics.record_cow(copied)
+            commit_span.set_metadata(retired=len(self._done) - n_done)
         return True

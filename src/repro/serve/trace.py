@@ -17,7 +17,8 @@ Three pieces:
     (`block_alloc` / `block_extend` / `block_free` / `block_share` /
     `cow_copy`), and per-step dispatch
     (`step_begin` / `step_end` with step kind, lane width, segment count,
-    fill and device time, plus `compile` when a step program traces).
+    fill and the dispatch's host time, plus `compile` when a step program
+    traces).
     Unknown event names are rejected loudly — the audit layer
     (`repro.serve.traceview`) depends on the taxonomy being closed.
   * `NullTraceRecorder` / `NULL_RECORDER` — the disabled path.  Emission
@@ -35,14 +36,32 @@ Three pieces:
     ignored by Perfetto, and the audit CLI
     (`python -m repro.serve.traceview trace.json`) reads them back to
     cross-validate the trace against the recorded aggregates.
+
+and, on the PROFILER's clock (the one a `jax.profiler` device trace uses):
+
+  * `span(name, **stats)` — a `jax.profiler.TraceAnnotation` for one of
+    the closed `SPAN_NAMES`: the engine's host phases of every step
+    (`serve.step` and its children).  Always on; with the profiler off a
+    span costs one Python object.
+  * `scope(name)` — a `jax.named_scope` for one of the closed
+    `SCOPE_NAMES`: the model parts of every step program.  HLO metadata
+    only; the arithmetic does not change.
+  * `program_scopes()` — per step program (HLO module name), every
+    optimized instruction's path of scope names, read from the executable
+    when it compiles (`launch`).  A device trace's ops carry only their
+    instruction names; this table names the model part each belongs to.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import re
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+import numpy as np
 
 # The closed event taxonomy.  Grouped by the subsystem that emits them.
 EVENT_TYPES = frozenset({
@@ -76,8 +95,43 @@ EVENT_TYPES = frozenset({
     # step dispatch (runtime.py)
     "step_begin",      # step, kind ("unified"|"decode_only"), lane_width,
                        #   segments, chunk_tokens, decode_rows
-    "step_end",        # step, kind, ... as begin, plus device_s
-    "compile",         # program ("unified"|"decode_only"|"commit"), device_s
+    "step_end",        # step, kind, ... as begin, plus host_s (host wall
+                       #   time of the dispatch: uploads, program, read-back)
+    "compile",         # program ("unified"|"decode_only"|"commit"), host_s
+})
+
+# The closed list of host spans (`span`), nested as listed: `serve.step`
+# holds the others, one each per step that runs a program.
+SPAN_NAMES = frozenset({
+    "serve.step",      # step (= step_begin's index), kind ("unified"|
+                       #   "decode_only"|"none"), decode_rows, chunk_tokens,
+                       #   segments, preempted
+    "serve.admit",     # scheduler admission + resume swap-ins: admitted,
+                       #   resumed
+    "serve.schedule",  # chunk packing, decode growth, chunk claims,
+                       #   preemption
+    "serve.inputs",    # the step's host arrays and their uploads
+    "serve.launch",    # the jitted step call (the enqueue): program (the
+                       #   HLO module name)
+    "serve.sync",      # read-back of the sampled tokens (waits on the device)
+    "serve.commit",    # tokens, first tokens, retirement, metrics, events:
+                       #   retired
+})
+
+# The closed list of named scopes (`scope`) in the step programs.  A path
+# nests them: lane / model part / layer part, e.g.
+# "decode_lane/layers/attention".  Ops the layer scan itself emits (the
+# per-layer slices of stacked weights and state pools, and their
+# write-back) read exactly ".../layers".
+SCOPE_NAMES = frozenset({
+    # step programs (launch/steps.py)
+    "prefill_lane", "decode_lane", "sample",
+    # model (models/zoo.py, models/common.py)
+    "embed", "layers", "final_norm", "lm_head",
+    # one layer: the pre-norms, attention or mamba mixer, MLP
+    "norm", "qkv_proj", "kv_write", "attention", "o_proj",
+    "mlp_up", "mlp_down",
+    "in_proj", "conv", "ssd", "out_proj",
 })
 
 
@@ -176,6 +230,155 @@ class NullTraceRecorder:
 NULL_RECORDER = NullTraceRecorder()
 
 
+# -------------------------------------------------- profiler spans and scopes
+def span(name: str, **stats) -> jax.profiler.TraceAnnotation:
+    """A host span on the profiler's clock: enter it with `with`; stats
+    known only later go in through `.set_metadata(**stats)`."""
+    if name not in SPAN_NAMES:
+        raise ValueError(f"unknown span {name!r}; the span names are "
+                         f"closed (see trace.SPAN_NAMES)")
+    return jax.profiler.TraceAnnotation(name, **stats)
+
+
+def scope(name: str):
+    """A named scope of the step programs (HLO metadata only)."""
+    if name not in SCOPE_NAMES:
+        raise ValueError(f"unknown scope {name!r}; the scope names are "
+                         f"closed (see trace.SCOPE_NAMES)")
+    return jax.named_scope(name)
+
+
+# HLO module name -> {instruction name: scope path}; the newest compile of
+# a module name wins
+_PROGRAM_SCOPES: Dict[str, Dict[str, str]] = {}
+
+_HLO_MODULE = re.compile(r"HloModule\s+([^\s,]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s*=\s*(.*)$")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r"\bop_name=\"([^\"]*)\"")
+_HLO_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
+
+
+def scope_path(op_name: str) -> str:
+    """'jit(f)/decode_lane/layers/while/body/attention/dot_general' ->
+    'decode_lane/layers/attention': the op's SCOPE_NAMES components, a
+    component directly under itself counted once."""
+    out: List[str] = []
+    for part in op_name.split("/"):
+        if part in SCOPE_NAMES and (not out or out[-1] != part):
+            out.append(part)
+    return "/".join(out)
+
+
+def scope_table(hlo_text: str) -> Tuple[str, Dict[str, str]]:
+    """(module name, {instruction: scope path}) of an optimized HLO
+    module's text.  An instruction's path comes from its metadata.  One
+    with none (the compiler inserts copies, async starts, buffers and
+    layout changes without metadata) takes the path of its first operand
+    that has one — not a constant's (the compiler shares one constant
+    among unrelated ops), and not for a tuple (it bundles unrelated
+    values) — else that of the instruction calling its computation (a
+    `while` for a loop body), else that of its first user that has one;
+    instructions left with no path are left out."""
+    m = _HLO_MODULE.search(hlo_text)
+    module = m.group(1) if m else ""
+    comp_of: Dict[str, str] = {}       # instruction -> its computation
+    refs_of: Dict[str, List[str]] = {}  # instruction -> names it refers to
+    own: Dict[str, str] = {}           # instruction -> path of its metadata
+    comps, constants, tuples = set(), set(), set()
+    comp = None
+    for line in hlo_text.splitlines():
+        c = _HLO_COMPUTATION.match(line)
+        if c:
+            comp = c.group(1)
+            comps.add(comp)
+            continue
+        i = _HLO_INSTRUCTION.match(line)
+        if i is None or comp is None:
+            continue
+        name, rest = i.groups()
+        comp_of[name] = comp
+        refs_of[name] = _HLO_REF.findall(rest)
+        op = _HLO_OP_NAME.search(rest)
+        own[name] = scope_path(op.group(1)) if op else ""
+        code = _HLO_OPCODE.search(rest)
+        code = code.group(1) if code else ""
+        if code == "constant":
+            constants.add(name)
+        elif code == "tuple":
+            tuples.add(name)
+    operands: Dict[str, List[str]] = {}
+    users: Dict[str, List[str]] = {}
+    caller: Dict[str, str] = {}        # computation -> a calling instruction
+    for name, refs in refs_of.items():
+        operands[name] = []
+        for r in refs:
+            if r in comps:
+                caller.setdefault(r, name)
+            elif comp_of.get(r) == comp_of[name]:
+                if r not in constants:
+                    operands[name].append(r)
+                users.setdefault(r, []).append(name)
+
+    table: Dict[str, str] = {}
+
+    def resolve(name: str) -> str:
+        if name not in table:
+            table[name] = ""           # a cycle resolves to no path
+            path = own[name]
+            for r in () if name in tuples else operands[name]:
+                if path:
+                    break
+                path = resolve(r)
+            if not path and comp_of[name] in caller:
+                path = resolve(caller[comp_of[name]])
+            for u in users.get(name, ()):
+                if path:
+                    break
+                path = resolve(u)
+            table[name] = path
+        return table[name]
+
+    for name in own:
+        resolve(name)
+    return module, {k: v for k, v in table.items() if v}
+
+
+def launch(prog, *args):
+    """Call the jitted step program `prog` inside a `serve.launch` span.
+    When the call built a new executable (compiled or loaded it), record
+    that executable's scope table.  Returns (outputs, compiled)."""
+    n = prog._cache_size()
+    with span("serve.launch", program=f"jit_{prog.__name__}"):
+        out = prog(*args)
+    compiled = prog._cache_size() > n
+    if compiled:
+        # the call's shapes and shardings (a committed array's sharding is
+        # part of the lowering); not the arrays: the donated pools are
+        # deleted by now.  The lowering and the executable are then jit's
+        # cached ones, so nothing compiles
+        def like(x):
+            committed = getattr(x, "committed", False)
+            return jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype,
+                sharding=x.sharding if committed else None)
+
+        abstract = [jax.tree.map(like, a) for a in args]
+        module, table = scope_table(
+            prog.lower(*abstract).compile().as_text())
+        _PROGRAM_SCOPES[module] = table
+    return out, compiled
+
+
+def program_scopes() -> Dict[str, Dict[str, str]]:
+    """Per HLO module name of a step program compiled in this process,
+    {instruction name: scope path}.  The "XLA Ops" events of a
+    `jax.profiler` device trace are named by instruction, so this names the
+    model part of every device op, with HLO protos off."""
+    return {k: dict(v) for k, v in _PROGRAM_SCOPES.items()}
+
+
 # --------------------------------------------------------------- metrics I/O
 def metrics_snapshot(metrics) -> Dict[str, Any]:
     """A JSON-serializable `ServeMetrics` snapshot for embedding next to a
@@ -263,8 +466,8 @@ def _scheduler_track_events(events: List[TraceEvent], t0: float) -> List[dict]:
             dur = (e.t - b.t) * 1e6 if b is not None else 0.0
             if dur <= 0.0:
                 # virtual-clock replays advance the clock BETWEEN steps, so
-                # begin/end coincide; fall back to measured device time
-                dur = e.fields.get("device_s", 0.0) * 1e6
+                # begin/end coincide; fall back to the dispatch's host time
+                dur = e.fields.get("host_s", 0.0) * 1e6
             out.append({"name": f"step:{e.fields.get('kind', '?')}",
                         "ph": "X", "pid": PID_SCHEDULER, "tid": 0,
                         "ts": ts, "dur": dur, "args": dict(e.fields)})
