@@ -269,9 +269,10 @@ class PlanRouter:
         (no `max_segments` key) fall back to single-segment behaviour:
         their block_q was only ever raced on the one-request grid.  An XLA
         choice — whatever the plan's age — and the no-plan case both defer
-        to the engine's own default: the XLA gather lane computes an
-        identical per-row program at every packing width, so there is no
-        tuned grid to protect and nothing to cap."""
+        to the engine's own default: the XLA lane gathers each segment's
+        table once and every row reduces over its own segment's full-width
+        view, an identical per-row program at every packing width, so there
+        is no tuned grid to protect and nothing to cap."""
         if self.plan is None:
             return max(1, default)
         c = self._lookup("prefill_chunk", "attention")

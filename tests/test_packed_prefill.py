@@ -2,9 +2,11 @@
 
 The load-bearing contracts pinned here:
 
-  * the segment-packed prefill Pallas kernel agrees with a from-scratch
-    per-segment gather reference at segment boundaries — 2-4 segments,
-    ragged lengths, GQA groupings, block_q tiles that straddle segments;
+  * the segment-packed prefill Pallas kernel and the XLA lane agree with
+    a from-scratch per-segment gather reference at segment boundaries —
+    2-4 segments, ragged lengths, GQA groupings, block_q tiles that
+    straddle segments — and the XLA lane's rows are bitwise the same
+    packed beside other segments as computed alone;
   * packing is invisible to the tokens: a `chunk_segments`-packed engine
     emits byte-identical greedy streams to a single-segment one (PR 4
     behaviour) and to an unlimited one, fast small case + slow multi-seed
@@ -68,12 +70,34 @@ def _packed_reference(q, k_pool, v_pool, seg_tables, seg_info):
     return out
 
 
-@pytest.mark.parametrize("seg_lens,kv_starts,block_q", [
+_SEGMENT_LAYOUTS = [
     ((5, 9), (7, 0), None),          # 2 segments, one resuming mid-prompt
     ((3, 4, 2), (0, 11, 5), 4),      # 3 ragged segments, tiles straddle
     ((6, 1, 8, 3), (2, 0, 9, 0), 8),  # 4 segments incl. a 1-token one
     ((11,), (13,), 4),               # single segment (PR 4 shape)
-])
+]
+
+
+def _packed_inputs(seg_lens, kv_starts, dtype=jnp.float32):
+    """q (1, C, 4, 16), K/V pools of 32 blocks of 8 with 2 KV heads,
+    disjoint 6-block tables per segment (distinct requests own distinct
+    blocks), and the segments packed from row 0 with two trailing padding
+    rows."""
+    rng = np.random.default_rng(11)
+    h, hkv, d, bs, nbt, nb = 4, 2, 16, 8, 6, 32
+    c = sum(seg_lens) + 2
+    ns = len(seg_lens)
+    q = jnp.asarray(rng.standard_normal((1, c, h, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal((nb, bs, hkv, d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((nb, bs, hkv, d)), dtype)
+    perm = rng.permutation(np.arange(1, nb))
+    seg_tables = np.asarray(perm[:ns * nbt]).reshape(ns, nbt).astype(np.int32)
+    q0s = np.concatenate([[0], np.cumsum(seg_lens)[:-1]])
+    seg_info = np.stack([q0s, seg_lens, kv_starts], axis=1).astype(np.int32)
+    return q, kp, vp, seg_tables, seg_info
+
+
+@pytest.mark.parametrize("seg_lens,kv_starts,block_q", _SEGMENT_LAYOUTS)
 def test_packed_prefill_kernel_matches_gather_reference(seg_lens, kv_starts,
                                                         block_q):
     """`flash_prefill_paged` (via the packed ops wrapper) must agree with a
@@ -81,19 +105,7 @@ def test_packed_prefill_kernel_matches_gather_reference(seg_lens, kv_starts,
     to its OWN request's committed rows only, causally, whatever block_q
     tiling cuts across the segment layout."""
     from repro.kernels import ops as K
-    rng = np.random.default_rng(11)
-    h, hkv, d, bs, nbt, nb = 4, 2, 16, 8, 6, 32
-    c = sum(seg_lens) + 2                      # two trailing padding rows
-    ns = len(seg_lens)
-    q = jnp.asarray(rng.standard_normal((1, c, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((nb, bs, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((nb, bs, hkv, d)), jnp.float32)
-    # disjoint per-segment tables (distinct requests own distinct blocks)
-    perm = rng.permutation(np.arange(1, nb))
-    seg_tables = np.asarray(perm[:ns * nbt]).reshape(ns, nbt).astype(np.int32)
-    q0s = np.concatenate([[0], np.cumsum(seg_lens)[:-1]])
-    seg_info = np.stack([q0s, seg_lens, kv_starts], axis=1).astype(np.int32)
-
+    q, kp, vp, seg_tables, seg_info = _packed_inputs(seg_lens, kv_starts)
     cfg = {"block_q": block_q} if block_q else None
     out = K.attention_prefill_packed(q, kp, vp, jnp.asarray(seg_tables),
                                      jnp.asarray(seg_info), config=cfg)
@@ -102,6 +114,49 @@ def test_packed_prefill_kernel_matches_gather_reference(seg_lens, kv_starts,
     for q0, qn, _ in seg_info:                 # compare real rows only
         np.testing.assert_allclose(got[q0:q0 + qn], ref[q0:q0 + qn],
                                    rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("seg_lens,kv_starts",
+                         [(lens, starts) for lens, starts, _ in
+                          _SEGMENT_LAYOUTS])
+def test_packed_prefill_xla_lane_matches_gather_reference(seg_lens,
+                                                          kv_starts):
+    """The XLA lane (each segment's table gathered once, each row scored
+    against its own segment's view) against the same reference, on the
+    kernel's segment layouts."""
+    from repro.models.attention import attention_prefill_packed_xla
+    q, kp, vp, seg_tables, seg_info = _packed_inputs(seg_lens, kv_starts)
+    out = jax.jit(attention_prefill_packed_xla)(
+        q, kp, vp, jnp.asarray(seg_tables), jnp.asarray(seg_info))
+    ref = _packed_reference(q[0], kp, vp, seg_tables, seg_info)
+    got = np.asarray(out[0])
+    for q0, qn, _ in seg_info:                 # compare real rows only
+        np.testing.assert_allclose(got[q0:q0 + qn], ref[q0:q0 + qn],
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_xla_lane_rows_are_packing_invariant():
+    """Bit for bit, in bf16: each segment's rows computed packed, at their
+    row offset beside the other segments, equal the same rows computed
+    alone at row 0 with the other descriptors idle.  The byte-identical
+    stream tests (packed vs single-segment vs unlimited) rest on this."""
+    from repro.models.attention import attention_prefill_packed_xla
+    q, kp, vp, seg_tables, seg_info = _packed_inputs(
+        (6, 1, 8, 3), (2, 0, 9, 0), jnp.bfloat16)
+    lane = jax.jit(attention_prefill_packed_xla)
+    packed = np.asarray(lane(q, kp, vp, seg_tables, seg_info)[0]
+                        .astype(jnp.float32))
+    ns = len(seg_info)
+    for s, (q0, qn, kv0) in enumerate(seg_info):
+        alone_q = jnp.zeros_like(q).at[0, :qn].set(q[0, q0:q0 + qn])
+        alone_tables = np.zeros_like(seg_tables)   # idle rows: null block
+        alone_tables[0] = seg_tables[s]
+        alone_info = np.asarray([[0, qn, kv0]] + [[qn, 0, 0]] * (ns - 1),
+                                np.int32)
+        alone = lane(alone_q, kp, vp, alone_tables, alone_info)
+        np.testing.assert_array_equal(
+            np.asarray(alone[0, :qn].astype(jnp.float32)),
+            packed[q0:q0 + qn], err_msg=f"segment {s}")
 
 
 def test_packed_row_map_assigns_rows_to_their_segments():

@@ -19,9 +19,11 @@ Three layers of pinning:
 """
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -392,3 +394,73 @@ def test_cross_mesh_streams_byte_identical_fuzz():
     assert payload["ndev"] == 4
     for run in payload["runs"]:
         _check_run(run)
+
+
+# --------------------------------------- packed-prefill XLA lane at tp=4
+# The lane on a 1x4 host mesh, pools and queries sharded on their head axes
+# as the serving step lays them out: it must match tp=1, and the compiled
+# (partitioned) program must gather each chip's segment context locally:
+# no collective at all, so no all-gather or all-to-all of a pool or of the
+# gathered context.
+_PACKED_LANE_TP = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    import json, re
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import tp_mesh
+    from repro.models.attention import attention_prefill_packed_xla
+
+    rng = np.random.default_rng(3)
+    h, hkv, d, bs, nbt, nb = 8, 4, 16, 8, 6, 32
+    seg_lens, kv_starts = (6, 1, 8, 3), (2, 0, 9, 0)
+    c, ns = sum(seg_lens) + 2, len(seg_lens)
+    q = rng.standard_normal((1, c, h, d)).astype(np.float32)
+    kp = rng.standard_normal((nb, bs, hkv, d)).astype(np.float32)
+    vp = rng.standard_normal((nb, bs, hkv, d)).astype(np.float32)
+    tables = rng.permutation(np.arange(1, nb))[:ns * nbt].reshape(
+        ns, nbt).astype(np.int32)
+    q0s = np.concatenate([[0], np.cumsum(seg_lens)[:-1]])
+    info = np.stack([q0s, seg_lens, kv_starts], axis=1).astype(np.int32)
+
+    def run(tp):
+        mesh = tp_mesh(tp)
+        heads = NamedSharding(mesh, P(None, None, "model", None))
+        rep = NamedSharding(mesh, P())
+        fn = jax.jit(attention_prefill_packed_xla,
+                     in_shardings=(heads, heads, heads, rep, rep),
+                     out_shardings=heads)
+        args = [jax.device_put(a, s) for a, s in
+                zip((q, kp, vp, tables, info),
+                    (heads, heads, heads, rep, rep))]
+        text = fn.lower(*args).compile().as_text()
+        coll = [ln.strip() for ln in text.splitlines()
+                if re.search(r"\\s(all-gather|all-to-all|all-reduce|"
+                             r"reduce-scatter|collective-permute)"
+                             r"(-start)?\\(", ln)]
+        return np.asarray(fn(*args)), coll
+
+    out1, _ = run(1)
+    out4, coll4 = run(4)
+    print(json.dumps({"ndev": len(jax.devices()),
+                      "max_abs": float(np.abs(out4 - out1).max()),
+                      "collectives": coll4}))
+""")
+
+
+def test_xla_packed_prefill_lane_stays_local_at_tp4():
+    """At tp=4 (4 KV heads, one per chip) the lane gathers each segment's
+    context from the chip's own pool shard: output equal to tp=1 within
+    float tolerance, and no collective in the partitioned program."""
+    out = subprocess.run([sys.executable, "-c", _PACKED_LANE_TP],
+                         capture_output=True, text=True, timeout=300,
+                         env={**os.environ, "PYTHONPATH": "src"},
+                         cwd=Path(__file__).resolve().parents[1])
+    assert out.returncode == 0, out.stderr[-3000:]
+    payload = json.loads(out.stdout.strip().splitlines()[-1])
+    assert payload["ndev"] == 4
+    assert payload["max_abs"] < 1e-5, payload
+    assert payload["collectives"] == [], payload["collectives"]
