@@ -21,7 +21,8 @@ A cell (`workloads` of BENCHMARK.json) names a model configuration
      `arrival_time` its due time, and stepping the engine itself — through
      a warm-up into the measured window of --seconds;
   7. checks what the window served against the plain float32 reference
-     (`bench/reference.py`);
+     the configuration names (`bench/modules.py`; by default
+     `bench/reference.py`);
   8. prints the cell's metrics as the last line of standard output: its
      end-to-end metrics with --trace 0, its per-layer metrics, read from a
      profiler trace of a slice of the window, with --trace 1 (the
@@ -30,6 +31,8 @@ A cell (`workloads` of BENCHMARK.json) names a model configuration
 
 Everything that belongs to one configuration, traffic mix or metric lives
 in a file of its own, found by name: configs/, traffic/, e2e/ and layers/.
+A configuration file names its program preset and fields (`program`),
+and may name its reference and its work counts (`bench/modules.py`).
 """
 
 from __future__ import annotations
@@ -41,7 +44,6 @@ PROCESS_START = time.perf_counter()
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import sys  # noqa: E402
@@ -56,6 +58,7 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(ROOT / "src"))
 
+import modules  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
 
 # the module each kind of engine step runs
@@ -93,11 +96,7 @@ def load_peaks(kind: str) -> Dict:
 
 def reader(kind: str, name: str):
     """The `read(run)` function of bench/<kind>/<name>.py."""
-    path = BENCH / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return modules.load(f"{kind}/{name}").read
 
 
 def metrics_for(bench: Dict, cell: str, section: str) -> List[Dict]:
@@ -171,19 +170,30 @@ class Run:
 
 
 # ---------------------------------------------------------------- set-up
+# the program's ModelConfig field for each published size (HF key)
+PROGRAM_SIZES = {"num_hidden_layers": ("n_layers", int),
+                 "hidden_size": ("d_model", int),
+                 "num_attention_heads": ("n_heads", int),
+                 "num_key_value_heads": ("n_kv_heads", int),
+                 "head_dim": ("head_dim", int),
+                 "intermediate_size": ("d_ff", int),
+                 "vocab_size": ("vocab", int),
+                 "rope_theta": ("rope_theta", float),
+                 "torch_dtype": ("dtype", str)}
+
+
 def program_config(cfg: Dict):
-    """The program's ModelConfig for a configuration file: its registry
-    preset with the file's sizes (equal to the preset for every cell)."""
+    """The program's ModelConfig for a configuration file: the registry
+    preset `program.arch`, with the published sizes the file gives
+    (`PROGRAM_SIZES`), then every further field of its `program` section.
+    Whatever else the block is (q/k norm, experts, ...) is the preset's."""
     from repro.configs import get_config
 
-    base = get_config(cfg["program"]["arch"])
-    return dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        qk_norm=cfg["model_type"] == "qwen3", dtype=cfg["torch_dtype"])
+    fields = dict(cfg["program"])
+    base = get_config(fields.pop("arch"))
+    sizes = {field: cast(cfg[key])
+             for key, (field, cast) in PROGRAM_SIZES.items() if key in cfg}
+    return dataclasses.replace(base, **{**sizes, **fields})
 
 
 def seed_key(seed: int):
@@ -428,18 +438,19 @@ def serve(server: Server, mix: Dict, seconds: float, origin: float,
 def check(cfg: Dict, params, run: Run, prompts: Dict[int, np.ndarray],
           seed: int, control: bool = False) -> Tuple[Dict, Optional[Dict]]:
     """Compare the served tokens of a sample of finished requests with the
-    reference: the widest gap by which a served token's reference logit
-    lies below the reference's best at its position.  Where the finished
-    requests hold fewer than the sample's tokens (a backlog finishes few
-    in a window), every request that was served a token is drawn from,
-    with the tokens it was served.
+    reference the configuration names (`modules.of_config`): the widest
+    gap by which a served token's reference logit lies below the
+    reference's best at its position.  Where the finished requests hold
+    fewer than the sample's tokens (a backlog finishes few in a window),
+    every request that was served a token is drawn from, with the tokens
+    it was served.
 
     With `control`, also the same checks with the control in the program's
     place: at each position of the same prompts and served tokens, the
     token the reference computed in float8 puts first, read under the
     float32 reference."""
-    import reference
-
+    shared = modules.load("reference")
+    ref_mod = modules.of_config(cfg, "reference")
     chk = cfg["check"]
     reqs = run.requests
     pool = [i for i, r in enumerate(reqs) if r.finished is not None]
@@ -471,12 +482,12 @@ def check(cfg: Dict, params, run: Run, prompts: Dict[int, np.ndarray],
         positions.append(np.arange(r.prompt_len - 1,
                                    r.prompt_len - 1 + len(out)))
         toks.append(out)
-    width = -(-max(len(s) for s in seqs) // reference.ROW_BLOCK) \
-        * reference.ROW_BLOCK
+    width = -(-max(len(s) for s in seqs) // shared.ROW_BLOCK) \
+        * shared.ROW_BLOCK
     t = time.perf_counter()
-    ref = reference.logits_at(cfg, params, seqs, positions,
-                              batch=chk["max_requests"], width=width)
-    gaps = np.concatenate([reference.served_gaps(lg, tk)
+    ref = ref_mod.logits_at(cfg, params, seqs, positions,
+                            batch=chk["max_requests"], width=width)
+    gaps = np.concatenate([shared.served_gaps(lg, tk)
                            for lg, tk in zip(ref, toks)])
     log(f"reference over {len(pick)} requests, {len(gaps)} served tokens, "
         f"width {width}: {time.perf_counter() - t:.1f} s")
@@ -486,10 +497,10 @@ def check(cfg: Dict, params, run: Run, prompts: Dict[int, np.ndarray],
            "max_logit_gap": {"value": float(gaps.max()), "limit": limit}}
     if not control:
         return out, None
-    low = reference.logits_at(cfg, params, seqs, positions,
-                              batch=chk["max_requests"], width=width,
-                              precision="fp8")
-    cgaps = np.concatenate([reference.served_gaps(r_, lw.argmax(axis=1))
+    low = ref_mod.logits_at(cfg, params, seqs, positions,
+                            batch=chk["max_requests"], width=width,
+                            precision="fp8")
+    cgaps = np.concatenate([shared.served_gaps(r_, lw.argmax(axis=1))
                             for r_, lw in zip(ref, low)])
     return out, dict(out, max_logit_gap={"value": float(cgaps.max()),
                                          "limit": limit})

@@ -11,6 +11,8 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
+import modules
+
 
 def percentile(values: List[float], p: float) -> Optional[float]:
     if not values:
@@ -27,30 +29,32 @@ def program_ms(run, module: str) -> Optional[float]:
 
 
 def roofline_pct(run, module: Optional[str]) -> Optional[float]:
-    """Least time the traced steps' work needs (bench/work.py at the
-    chip's peaks) over their measured device time, in percent; `module`
-    None takes the steps of every program."""
+    """Least time the traced steps' work needs (the `step_work` of the
+    configuration's work module, at the chip's peaks) over their measured
+    device time, in percent; `module` None takes the steps of every
+    program."""
     import work
 
+    step_work = modules.of_config(run.config, "work").step_work
     need = spent = 0.0
     for s in run.traced_steps:
         name, dev_s = run.step_device[s.idx]
         if module is not None and name != module:
             continue
-        flops, nbytes = work.step_work(run.config, s.decode_keys, s.segments)
+        flops, nbytes = step_work(run.config, s.decode_keys, s.segments)
         need += work.least_time_s(flops, nbytes, run.peaks, run.chips)[0]
         spent += dev_s
     return 100.0 * need / spent if spent > 0 else None
 
 
 def mfu_pct(run) -> Optional[float]:
-    """Model operations of the traced steps over their host wall time times
-    the chips' peak, in percent."""
-    import work
-
+    """Model operations of the traced steps (the configuration's work
+    module) over their host wall time times the chips' peak, in
+    percent."""
+    step_work = modules.of_config(run.config, "work").step_work
     flops = wall = 0.0
     for s in run.traced_steps:
-        flops += work.step_work(run.config, s.decode_keys, s.segments)[0]
+        flops += step_work(run.config, s.decode_keys, s.segments)[0]
         wall += s.t_end - s.t_begin
     if wall <= 0:
         return None

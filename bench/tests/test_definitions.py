@@ -1,7 +1,9 @@
 """BENCHMARK.json and the files it names agree: every cell's configuration
 and traffic mix exist, every metric has its reader, and each
-configuration file describes its program preset unchanged."""
+configuration file describes its program: the preset, with the fields its
+`program` section replaces."""
 
+import dataclasses
 import json
 
 import pytest
@@ -46,9 +48,19 @@ def test_every_cell_reports_its_metrics():
 @pytest.mark.parametrize("name", sorted(
     p.stem for p in (run.BENCH / "configs").glob("*.json")))
 def test_config_is_the_program_preset(name):
+    """The program is the preset with exactly the fields of the file's
+    `program` section beside `arch` replaced, each of them explained in
+    the file (`reduced`, `why_reduced` or `assumed`, as the field or as
+    `program.<field>`)."""
     cfg = run.load_config(name)
     assert cfg["name"] == name
-    assert run.program_config(cfg) == get_config(cfg["program"]["arch"])
+    fields = {k: v for k, v in cfg["program"].items() if k != "arch"}
+    preset = get_config(cfg["program"]["arch"])
+    assert run.program_config(cfg) == dataclasses.replace(preset, **fields)
+    explained = (set(cfg["reduced"]) | set(cfg.get("why_reduced", {}))
+                 | set(cfg.get("assumed", {})))
+    for f in fields:
+        assert {f, f"program.{f}"} & explained, f
 
 
 def test_peaks_have_a_source():

@@ -10,7 +10,8 @@ import traffic
 SEED = 2**31 + 12345
 
 
-@pytest.fixture(params=["chat", "chat-backlog"])
+@pytest.fixture(params=sorted(p.stem
+                              for p in traffic.TRAFFIC_DIR.glob("*.json")))
 def mix(request):
     return traffic.load(request.param)
 
